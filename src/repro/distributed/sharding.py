@@ -20,75 +20,40 @@ from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (AbstractMesh, AxisType, Mesh, NamedSharding,
+                          PartitionSpec as P)
 
 MODEL_AXIS = "model"
 
 
-# --------------------------------------------------------------------------
-# jax version tolerance. The sharding surface moved between jax releases:
-# ``AbstractMesh`` flipped from ``((name, size), ...)`` pairs to positional
-# ``(sizes, names)``; ``jax.sharding.get_abstract_mesh`` / ``jax.set_mesh`` /
-# ``jax.shard_map`` / ``AxisType`` only exist on newer jax. Everything in
-# this repo goes through these helpers instead of calling jax directly.
-# --------------------------------------------------------------------------
+# Every sharding call in the repo goes through these helpers, so the JAX
+# surface it depends on is named in one place.
 
 def make_abstract_mesh(shape, axes) -> AbstractMesh:
-    """Build an ``AbstractMesh`` from ``shape``/``axes`` on any jax version.
-
-    Newer jax takes ``AbstractMesh(axis_sizes, axis_names)``; older jax takes
-    one ``shape_tuple`` of ``(name, size)`` pairs.
-    """
-    shape = tuple(shape)
-    axes = tuple(axes)
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """An ``AbstractMesh`` with ``shape``/``axes``, no devices attached."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API has them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis of ``Auto`` type."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def get_abstract_mesh():
-    """The mesh of the current context (set via :func:`use_mesh`).
-
-    Newer jax exposes ``jax.sharding.get_abstract_mesh``; older jax tracks
-    the physical mesh in thread-local state — the physical ``Mesh`` carries
-    the same ``axis_names`` / ``shape`` mapping, so callers can treat the
-    two uniformly (and pass either to :func:`shard_map`).
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    from jax._src import mesh as mesh_lib
-    return mesh_lib.thread_resources.env.physical_mesh
+    """The mesh of the current context (set via :func:`use_mesh`)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def use_mesh(mesh: Mesh):
-    """Context manager activating ``mesh`` (``jax.set_mesh`` on newer jax,
-    the legacy ``with mesh:`` resource context otherwise)."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is None:
-        setter = getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh          # old jax: Mesh is its own context manager
+    """Context manager activating ``mesh``."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` where it exists, the experimental one otherwise."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` over ``mesh``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 def data_axes(mesh: Mesh) -> tuple:

@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_DRYRUN_XLA_FLAGS")
                            or "--xla_force_host_platform_device_count=512")
 
@@ -6,8 +7,12 @@ os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_DRYRUN_XLA_FLAGS")
 (architecture × input-shape × mesh) cell against the production mesh and
 record memory / cost / collective artifacts for the roofline analysis.
 
-The two lines above MUST stay first: jax locks the device count on first
+The lines above MUST stay first: jax locks the device count on first
 init, and only the dry-run wants 512 host devices (tests/benches see 1).
+The dry-run emulates its mesh on host devices, so it pins the CPU platform:
+a dry-run process never claims an accelerator, and the children that
+``benchmarks/roofline_bench.py`` starts cannot contend for a chip that
+their parent holds.
 
 Usage:
     python -m repro.launch.dryrun --arch starcoder2-15b --shape train_4k
@@ -190,8 +195,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):      # older jax: one dict per program
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     colls = collective_summary(hlo)
     n_dev = len(jax.devices())

@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.engines import EngineSpec, list_kv_engines
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.models import build_model
 from repro.serving import Request, ServeConfig, ServingEngine
 
@@ -97,6 +98,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compilation_cache()
     cfg = get_config(args.arch)
     model = build_model(cfg, remat=False)
     params = model.init(jax.random.PRNGKey(args.seed))
@@ -169,7 +171,9 @@ def main(argv=None):
     mode = ("sequential" if args.sequential else
             ("batched+pooled" if engine.pooled else "batched+mirror")
             + ("+fused" if engine.fused else ""))
-    print(f"tiered-kv[{args.design}] ({mode}) stats: {engine.stats()}")
+    dev = jax.devices()[0]
+    print(f"tiered-kv[{args.design}] ({mode}) on {dev.platform} "
+          f"{dev.device_kind} x{len(jax.devices())} stats: {engine.stats()}")
 
 
 if __name__ == "__main__":

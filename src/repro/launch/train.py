@@ -1,9 +1,9 @@
 """End-to-end training driver.
 
-Runs on whatever devices exist (CPU here; the production mesh path is
-exercised by dryrun.py). Integrates: synthetic data pipeline, AdamW
-(+WSD for minicpm), microbatching, checkpoint/restart through the paper's
-cache designs, and crash recovery.
+Runs on the first device JAX finds and names it in its last line (the
+production mesh path is exercised by dryrun.py). Integrates: synthetic data
+pipeline, AdamW (+WSD for minicpm), microbatching, checkpoint/restart
+through the paper's cache designs, and crash recovery.
 
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b-smoke \
         --steps 50 --ckpt-design log --ckpt-every 10
@@ -20,6 +20,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import SyntheticLMDataset, make_batch_iterator
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.models import build_model
 from repro.training import AdamWConfig, init_train_state, make_train_step
 
@@ -38,6 +39,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compilation_cache()
     cfg = get_config(args.arch)
     model = build_model(cfg, remat=True)
     opt_cfg = AdamWConfig(lr=args.lr, schedule=cfg.lr_schedule,
@@ -75,7 +77,9 @@ def main(argv=None):
                   f"sim_save={t*1e3:.2f}ms")
     dt = time.time() - t0
     tokens = args.steps * args.batch * args.seq
-    print(f"done: {dt:.1f}s wall, {tokens/dt:.0f} tok/s (CPU)")
+    dev = jax.devices()[0]
+    print(f"done: {dt:.1f}s wall, {tokens/dt:.0f} tok/s "
+          f"({dev.platform} {dev.device_kind} x{len(jax.devices())})")
     return float(metrics["loss"])
 
 
