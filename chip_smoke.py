@@ -1,6 +1,6 @@
 """Serve InternLM2-1.8B at its published widths on one TPU chip, and check it.
 
-    python3 chip_smoke.py [--seed N] [--requests N] [--max-new N]
+    python3 chip_smoke.py [--seed N]
 
 The run goes through the entry points a user calls: ``build_model``,
 ``ServingEngine`` (the ``paged`` engine with its device page pool, fused
@@ -15,11 +15,11 @@ TPU and when any check fails:
   reference, within ``KERNEL_TOL``;
 * the engine serves pooled and fused, the lowered fused step holds the Pallas
   kernel (``tpu_custom_call``), and no byte goes through the host mirror;
-* every request gets its ``max_new`` tokens, and a second pass over the same
+* every request gets its ``MAX_NEW`` tokens, and a second pass over the same
   requests produces the same tokens;
-* for ``LOGIT_CHECKS`` requests, the served logits at the last prompt
-  position against ``model.prefill`` (the dense XLA path, no Pallas) on the
-  same prompt, within ``LOGIT_TOL``.
+* for ``LOGIT_CHECKS`` requests, the served logits at every position of the
+  chunk that ends the prompt against ``model.prefill`` (the dense XLA path,
+  no Pallas) on the same prompt, within ``LOGIT_TOL``.
 
 Earlier lines report compile seconds, wall seconds, tokens served, step
 compiles and peak device memory. They describe this run and are not
@@ -57,12 +57,17 @@ KV_HBM_BYTES = 1 << 30          # about 680 pages of 16 tokens at bf16
 PREFILL_CHUNK = 256
 MAX_BATCH_SEQS = 8
 PROMPT_LENS = (256, 1024)       # inclusive range of seeded prompt lengths
+REQUESTS = 16
+MAX_NEW = 32
 # Kernel vs reference, both accumulating in f32 from bf16 inputs: the
 # outputs differ by the bf16 rounding of the result plus summation order.
 KERNEL_TOL = 2e-2               # atol and rtol
 # Served logits vs model.prefill, both bf16 through 24 layers by different
-# attention code: max |diff| <= LOGIT_TOL * max |reference logit|.
-LOGIT_TOL = 5e-2
+# attention code: max |diff| <= LOGIT_TOL * max |reference logit| over every
+# position of the last prompt chunk. On a TPU v5e at seed 0 the served path
+# differs by up to 1.6%; with the causal mask shifted by one key it differs
+# by 2.7% or more, with one page per row read from another row by 19%.
+LOGIT_TOL = 2e-2
 LOGIT_CHECKS = 3
 
 
@@ -192,9 +197,10 @@ def make_engine(model, params, max_len: int, kv_hbm_bytes: int,
 
 
 def capture_prompt_logits(engine: ServingEngine, prompt_lens: dict) -> dict:
-    """Record, for each rid in ``prompt_lens``, the served logits at its last
-    prompt position: those of the fused step whose chunk ends the prompt.
-    The engine's ``step_batch`` is wrapped; the dict fills as it serves."""
+    """Record, for each rid in ``prompt_lens``, the served logits at every
+    position of the chunk that ends its prompt, shaped ``(chunk, V)``: the
+    rows of the fused step whose chunk reaches the prompt's length. The
+    engine's ``step_batch`` is wrapped; the dict fills as it serves."""
     seen: dict = {}
     step = engine.step_batch
 
@@ -205,7 +211,7 @@ def capture_prompt_logits(engine: ServingEngine, prompt_lens: dict) -> dict:
             if n is None or rid in seen:
                 continue
             if int(caches[i]["pos"][0]) + len(tok_rows[i]) == n:
-                seen[rid] = np.asarray(out[0][i][0, -1], np.float32)
+                seen[rid] = np.asarray(out[0][i][0], np.float32)
         return out
 
     engine.step_batch = recording_step
@@ -216,7 +222,8 @@ def serve(model, params, requests: list, max_len: int, watch: list,
           kv_hbm_bytes: int, chunk: int):
     """Serve ``requests`` through ``generate()`` on a fresh engine. Returns
     the engine, the wall seconds (the tokens are on the host, so the device
-    has finished) and the served last-prompt logits of the ``watch`` rids."""
+    has finished) and the served logits of the ``watch`` rids' last prompt
+    chunks."""
     engine = make_engine(model, params, max_len, kv_hbm_bytes, chunk)
     if not (engine.pooled and engine.fused):
         raise RuntimeError(f"engine is not pooled and fused: pooled="
@@ -245,32 +252,39 @@ def check_served(engine: ServingEngine, requests: list) -> int:
 
 def check_logits(model, params, requests: list, served: dict,
                  watch: list, max_len: int) -> dict:
-    """The served last-prompt logits of the ``watch`` rids against
-    ``model.prefill`` on the same prompt. Returns
+    """The served logits of the ``watch`` rids' last prompt chunk against
+    ``model.prefill`` on the same prompt, position by position. Every
+    position is held to ``LOGIT_TOL`` of the reference's largest logit, so
+    a wrong page or a missing causal mask inside the chunk shows even where
+    the last position alone would not. Returns
     ``{rid: (max |diff|, tolerance)}``."""
     missing = [rid for rid in watch if rid not in served]
     if missing or not watch:
         raise RuntimeError(f"no served logits recorded for rids {missing} "
                            f"(watched {watch})")
     prefill = jax.jit(
-        lambda p, t: model.prefill(p, {"tokens": t}, max_len)[0])
+        lambda p, t, n: model.prefill(p, {"tokens": t}, max_len, n)[0],
+        static_argnums=2)
     V = model.cfg.vocab_size
     out = {}
     for r in requests:
         if r.rid not in watch:
             continue
-        ref = np.asarray(prefill(params, jnp.asarray(r.prompt[None])),
-                         np.float32)[0, -1, :V]
-        got = served[r.rid][:V]
+        got = served[r.rid][:, :V]
+        ref = np.asarray(prefill(params, jnp.asarray(r.prompt[None]),
+                                 len(got)), np.float32)[0, :, :V]
         err = float(np.max(np.abs(got - ref)))
         tol = LOGIT_TOL * float(np.max(np.abs(ref)))
-        top2 = np.sort(ref)[-2:]
         if not np.all(np.isfinite(got)) or err > tol:
             raise RuntimeError(f"rid {r.rid}: served logits differ from "
                                f"model.prefill by {err} > {tol}")
-        if top2[1] - top2[0] > tol and np.argmax(got) != np.argmax(ref):
-            raise RuntimeError(f"rid {r.rid}: served top-1 {np.argmax(got)} "
-                               f"!= reference top-1 {np.argmax(ref)}")
+        top2 = np.sort(ref, axis=-1)[:, -2:]
+        decided = top2[:, 1] - top2[:, 0] > tol
+        wrong = decided & (np.argmax(got, -1) != np.argmax(ref, -1))
+        if np.any(wrong):
+            raise RuntimeError(f"rid {r.rid}: served top-1 differs from the "
+                               f"reference at chunk positions "
+                               f"{np.flatnonzero(wrong).tolist()}")
         out[r.rid] = (err, tol)
     return out
 
@@ -292,8 +306,6 @@ def fused_step_has_kernel(model, params, engine: ServingEngine) -> bool:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--requests", type=int, default=16)
-    ap.add_argument("--max-new", type=int, default=32)
     args = ap.parse_args(argv)
 
     device = device_line()
@@ -314,10 +326,10 @@ def main(argv=None) -> int:
                         compute_dtype=jnp.bfloat16, remat=False)
     params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     print(f"after init: peak_bytes_in_use {peak_bytes()}", flush=True)
-    max_len = PROMPT_LENS[1] + args.max_new
+    max_len = PROMPT_LENS[1] + MAX_NEW
     max_len += -max_len % PAGE_TOKENS
-    requests = make_requests(cfg.vocab_size, args.requests, args.max_new,
-                             args.seed, PROMPT_LENS)
+    requests = make_requests(cfg.vocab_size, REQUESTS, MAX_NEW, args.seed,
+                             PROMPT_LENS)
     watch = [r.rid for r in requests
              if len(r.prompt) > PREFILL_CHUNK][:LOGIT_CHECKS]
 
@@ -338,8 +350,8 @@ def main(argv=None) -> int:
                            "the Pallas kernel is not in the program")
     print("fused step holds the Pallas kernel (tpu_custom_call)", flush=True)
 
-    again = make_requests(cfg.vocab_size, args.requests, args.max_new,
-                          args.seed, PROMPT_LENS)
+    again = make_requests(cfg.vocab_size, REQUESTS, MAX_NEW, args.seed,
+                          PROMPT_LENS)
     engine2, wall2, _ = serve(model, params, again, max_len, [],
                               KV_HBM_BYTES, PREFILL_CHUNK)
     n2, h2, s2 = compiles.snapshot()

@@ -199,19 +199,10 @@ def _paged_ragged_call(q, planes, block_table, lengths, q_lens, *, scale,
 def paged_attention_ragged_pallas(q, pool_k, pool_v, block_table, lengths,
                                   q_lens, *, scale: float | None = None,
                                   interpret: bool = False):
-    """Ragged-query single-layer entry: q (B, Qmax, H, D); pool_k/v
-    (P, T, K, D); block_table (B, MP); lengths/q_lens (B,)."""
-    return _paged_ragged_call(q, (pool_k, pool_v), block_table, lengths,
-                              q_lens, scale=scale, interpret=interpret)
-
-
-def paged_attention_layers_ragged_pallas(q, pool_k, pool_v, block_table,
-                                         lengths, q_lens, *,
-                                         scale: float | None = None,
-                                         interpret: bool = False):
-    """Ragged-query batched multi-layer entry: q (L, B, Qmax, H, D);
-    pool_k/v (L, P, T, K, D); block_table (B, MP); lengths/q_lens (B,)
-    shared by every layer."""
+    """Ragged-query entry: q (B, Qmax, H, D); pool_k/v (P, T, K, D);
+    block_table (B, MP); lengths/q_lens (B,). With a leading layer axis —
+    q (L, B, Qmax, H, D), pools (L, P, T, K, D) — it is the batched
+    multi-layer launch, the block table and lengths shared by every layer."""
     return _paged_ragged_call(q, (pool_k, pool_v), block_table, lengths,
                               q_lens, scale=scale, interpret=interpret)
 
@@ -220,21 +211,10 @@ def paged_attention_ragged_q8_pallas(q, pool_k, pool_v, pool_ks, pool_vs,
                                      block_table, lengths, q_lens, *,
                                      scale: float | None = None,
                                      interpret: bool = False):
-    """int8 ragged single-layer entry: q (B, Qmax, H, D); pool_k/v
-    (P, T, K, D) int8; pool_ks/vs (P, T, K) scale planes; block_table
-    (B, MP); lengths/q_lens (B,)."""
-    return _paged_ragged_call(q, (pool_k, pool_v, pool_ks, pool_vs),
-                              block_table, lengths, q_lens, scale=scale,
-                              interpret=interpret)
-
-
-def paged_attention_layers_ragged_q8_pallas(q, pool_k, pool_v, pool_ks,
-                                            pool_vs, block_table, lengths,
-                                            q_lens, *,
-                                            scale: float | None = None,
-                                            interpret: bool = False):
-    """int8 ragged multi-layer entry: q (L, B, Qmax, H, D); pool_k/v
-    (L, P, T, K, D) int8; pool_ks/vs (L, P, T, K); shared block table."""
+    """int8 ragged entry: q (B, Qmax, H, D); pool_k/v (P, T, K, D) int8;
+    pool_ks/vs (P, T, K) scale planes; block_table (B, MP); lengths/q_lens
+    (B,). A leading layer axis on q and the four planes makes it the
+    multi-layer launch."""
     return _paged_ragged_call(q, (pool_k, pool_v, pool_ks, pool_vs),
                               block_table, lengths, q_lens, scale=scale,
                               interpret=interpret)
@@ -244,23 +224,19 @@ def paged_attention_pallas(q, pool_k, pool_v, block_table, lengths, *,
                            scale: float | None = None,
                            interpret: bool = False):
     """Decode entry, one query per row: q (B, H, D); pool_k/v (P, T, K, D);
-    block_table (B, MP); lengths (B,)."""
+    block_table (B, MP); lengths (B,). With a leading layer axis on q and
+    the pools it is the multi-layer decode launch."""
     ones = jnp.ones(lengths.shape, jnp.int32)
     return paged_attention_ragged_pallas(
-        q[:, None], pool_k, pool_v, block_table, lengths, ones, scale=scale,
-        interpret=interpret)[:, 0]
+        jnp.expand_dims(q, -3), pool_k, pool_v, block_table, lengths, ones,
+        scale=scale, interpret=interpret)[..., 0, :, :]
 
 
-def paged_attention_layers_pallas(q, pool_k, pool_v, block_table, lengths, *,
-                                  scale: float | None = None,
-                                  interpret: bool = False):
-    """Batched multi-layer decode entry: q (L, B, H, D); pool_k/v
-    (L, P, T, K, D); block_table (B, MP) shared across layers; lengths
-    (B,)."""
-    ones = jnp.ones(lengths.shape, jnp.int32)
-    return paged_attention_layers_ragged_pallas(
-        q[:, :, None], pool_k, pool_v, block_table, lengths, ones,
-        scale=scale, interpret=interpret)[:, :, 0]
+# The multi-layer entries are the same calls: the builders read the layer
+# axis from the shapes.
+paged_attention_layers_ragged_pallas = paged_attention_ragged_pallas
+paged_attention_layers_ragged_q8_pallas = paged_attention_ragged_q8_pallas
+paged_attention_layers_pallas = paged_attention_pallas
 
 
 # ---------------------------------------------------------------------------
@@ -382,19 +358,13 @@ def _mla_ragged_call(q_c, q_r, pool_c, pool_kr, block_table, lengths,
 def mla_paged_attention_ragged_pallas(q_c, q_r, pool_c, pool_kr, block_table,
                                       lengths, q_lens, *, scale: float,
                                       interpret: bool = False):
-    """MLA ragged single-layer entry: q_c (B, Qmax, H, dc) absorbed
-    queries; q_r (B, Qmax, H, dr) rope queries; pool_c (P, T, dc) latent
-    plane; pool_kr (P, T, dr) rope-key plane. Returns the attended latent
-    o_c (B, Qmax, H, dc)."""
+    """MLA ragged entry: q_c (B, Qmax, H, dc) absorbed queries; q_r
+    (B, Qmax, H, dr) rope queries; pool_c (P, T, dc) latent plane; pool_kr
+    (P, T, dr) rope-key plane. Returns the attended latent o_c
+    (B, Qmax, H, dc). A leading layer axis on the queries and planes makes
+    it the multi-layer launch."""
     return _mla_ragged_call(q_c, q_r, pool_c, pool_kr, block_table, lengths,
                             q_lens, scale=scale, interpret=interpret)
 
 
-def mla_paged_attention_layers_ragged_pallas(q_c, q_r, pool_c, pool_kr,
-                                             block_table, lengths, q_lens, *,
-                                             scale: float,
-                                             interpret: bool = False):
-    """MLA ragged multi-layer entry: q_c (L, B, Qmax, H, dc); q_r
-    (L, B, Qmax, H, dr); pool_c (L, P, T, dc); pool_kr (L, P, T, dr)."""
-    return _mla_ragged_call(q_c, q_r, pool_c, pool_kr, block_table, lengths,
-                            q_lens, scale=scale, interpret=interpret)
+mla_paged_attention_layers_ragged_pallas = mla_paged_attention_ragged_pallas

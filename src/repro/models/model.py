@@ -332,8 +332,9 @@ class LM:
         widths[2] = (0, pad)
         return jnp.pad(kv, widths)
 
-    def prefill(self, params, batch, max_len: int):
-        """Run the prompt, return (last-position logits, decode cache)."""
+    def prefill(self, params, batch, max_len: int, n_logits: int = 1):
+        """Run the prompt, return (logits of the last ``n_logits``
+        positions, decode cache)."""
         cfg = self.cfg
         params = jax.tree.map(
             lambda a: a.astype(self.compute_dtype)
@@ -405,7 +406,7 @@ class LM:
             raise ValueError(fam)
 
         h = rmsnorm(params["final_ln"], h, cfg.norm_eps)
-        logits = self._logits(params, h[:, -1:])
+        logits = self._logits(params, h[:, -n_logits:])
         return logits, cache
 
     # ---------------------------------------------------- paged decode step

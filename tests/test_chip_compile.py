@@ -106,7 +106,11 @@ def test_mla_ragged_kernel_compiles_for_v5e(one_chip, qmax):
 @pytest.mark.parametrize("qmax", [1, QMAX])
 def test_fused_step_compiles_and_fits_v5e(one_chip, monkeypatch, qmax):
     """``LM.step_paged_ragged`` for InternLM2-1.8B in bf16 over a 1 GiB
-    pool: the Pallas kernel is in the program and the program fits HBM.
+    pool: the Pallas kernel is in the program, and two steps fit HBM.
+    ``memory_analysis`` bounds one step only (arguments, outputs and
+    temporaries). Served on the chip, the same run peaks at about twice
+    that, since no jit donates the pool; until the peak is attributed and
+    the pool donated, the test asks room for two steps in flight.
     The kernel entries pick Pallas by ``jax.default_backend()``, which sees
     the CPU here, so the test steers them to the TPU branch."""
     cfg = get_config("internlm2-1.8b")
@@ -132,4 +136,5 @@ def test_fused_step_compiles_and_fits_v5e(one_chip, monkeypatch, qmax):
     ma = compiled.memory_analysis()
     live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
-    assert live <= V5E_HBM_BYTES, f"{live} bytes do not fit one v5e chip"
+    assert 2 * live <= V5E_HBM_BYTES, (
+        f"two steps of {live} bytes do not fit one v5e chip")
