@@ -341,8 +341,9 @@ def main(argv=None) -> int:
     stats = engine.stats()
     print(f"serve pass 1: {tokens} tokens for {len(requests)} requests in "
           f"{wall:.3f} s wall, compile {s1 - s0:.3f} s for {n1 - n0} programs "
-          f"({h1 - h0} from the persistent cache), step_compiles "
-          f"{stats['step_compiles']}, fused_steps {stats['fused_steps']}, "
+          f"({h1 - h0} from the persistent cache), step programs "
+          f"{stats['compiles.jit(step_paged_ragged)']}, fused_steps "
+          f"{stats['fused_steps']}, "
           f"pool_pages {engine.tiered.pool_pages}, peak_bytes_in_use "
           f"{peak_bytes()}", flush=True)
     if not fused_step_has_kernel(model, params, engine):

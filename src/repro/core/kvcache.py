@@ -346,7 +346,9 @@ class PagedKVCache(_TieredKV):
 
     def _page_planes_np(self, phys: int) -> dict:
         """Materialize device page ``phys`` as host arrays, one
-        ``(L, T, *shape)`` per plane."""
+        ``(L, T, *shape)`` per plane (one host sync each)."""
+        from repro.serving.trace import TRACER
+        TRACER.count("host_syncs", len(self._plane_names))
         return {n: np.asarray(self.dev_planes[n][:, phys])
                 for n in self._plane_names}
 

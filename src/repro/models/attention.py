@@ -400,8 +400,11 @@ def attn_step_paged_ragged(params, cfg, x, pool_k, pool_v, block_table,
     phys = jnp.take_along_axis(block_table, logical, axis=1)       # (B, Qm)
     phys = jnp.where(valid, phys, P)               # out of range → dropped
     slot = positions % T
-    pool_k = pool_k.at[phys, slot].set(k.astype(pool_k.dtype), mode="drop")
-    pool_v = pool_v.at[phys, slot].set(v.astype(pool_v.dtype), mode="drop")
+    with jax.named_scope("kv_write"):
+        pool_k = pool_k.at[phys, slot].set(k.astype(pool_k.dtype),
+                                           mode="drop")
+        pool_v = pool_v.at[phys, slot].set(v.astype(pool_v.dtype),
+                                           mode="drop")
     out = paged_attention_ragged(
         q.reshape(B, Qm, H, D), pool_k, pool_v, block_table,
         ctx_lens + q_lens, q_lens, scale=1.0 / math.sqrt(D))
@@ -606,10 +609,11 @@ def attn_step_paged_ragged_q8(params, cfg, x, pool_k, pool_v, pool_ks,
     slot = positions % T
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
-    pool_k = pool_k.at[phys, slot].set(kq, mode="drop")
-    pool_v = pool_v.at[phys, slot].set(vq, mode="drop")
-    pool_ks = pool_ks.at[phys, slot].set(ks, mode="drop")
-    pool_vs = pool_vs.at[phys, slot].set(vs, mode="drop")
+    with jax.named_scope("kv_write"):
+        pool_k = pool_k.at[phys, slot].set(kq, mode="drop")
+        pool_v = pool_v.at[phys, slot].set(vq, mode="drop")
+        pool_ks = pool_ks.at[phys, slot].set(ks, mode="drop")
+        pool_vs = pool_vs.at[phys, slot].set(vs, mode="drop")
     out = paged_attention_ragged_q8(
         q.reshape(B, Qm, H, D), pool_k, pool_v, pool_ks, pool_vs,
         block_table, ctx_lens + q_lens, q_lens, scale=1.0 / math.sqrt(D))
@@ -716,10 +720,11 @@ def mla_step_paged_ragged(params, cfg, x, pool_c, pool_kr, block_table,
     phys = jnp.take_along_axis(block_table, logical, axis=1)
     phys = jnp.where(valid, phys, P)
     slot = positions % T
-    pool_c = pool_c.at[phys, slot].set(c_new.astype(pool_c.dtype),
-                                       mode="drop")
-    pool_kr = pool_kr.at[phys, slot].set(kr_new.astype(pool_kr.dtype),
-                                         mode="drop")
+    with jax.named_scope("kv_write"):
+        pool_c = pool_c.at[phys, slot].set(c_new.astype(pool_c.dtype),
+                                           mode="drop")
+        pool_kr = pool_kr.at[phys, slot].set(kr_new.astype(pool_kr.dtype),
+                                             mode="drop")
     q_c = jnp.einsum("bshd,chd->bshc", q_nope.astype(jnp.float32),
                      params["w_uk"].astype(jnp.float32))
     scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)

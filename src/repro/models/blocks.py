@@ -128,23 +128,28 @@ def step_paged_ragged_block(params, cfg, h, planes, block_table, ctx_lens,
     """Ragged multi-token block over one layer's pool-plane tuple (the
     fused mixed-batch tick). Plane dispatch as ``decode_paged_block``."""
     rs = cfg.residual_scale
-    x = rmsnorm(params["ln_attn"], h, cfg.norm_eps)
-    if cfg.mla is not None:
-        a, *planes = attn_mod.mla_step_paged_ragged(
-            params["attn"], cfg, x, planes[0], planes[1], block_table,
-            ctx_lens, q_lens)
-    elif len(planes) == 4:
-        a, *planes = attn_mod.attn_step_paged_ragged_q8(
-            params["attn"], cfg, x, planes[0], planes[1], planes[2],
-            planes[3], block_table, ctx_lens, q_lens)
-    else:
-        a, *planes = attn_mod.attn_step_paged_ragged(
-            params["attn"], cfg, x, planes[0], planes[1], block_table,
-            ctx_lens, q_lens)
-    h = h + rs * a
-    x = rmsnorm(params["ln_ffn"], h, cfg.norm_eps)
-    f = _apply_block_ffn(params, cfg, x, ffn_kind, ep_axes)
-    return h + rs * f, tuple(planes)
+    # named scopes: the layer's ops carry ``attn`` (``kv_write`` for the
+    # pool scatter) or ``mlp`` in their op_name metadata, so a device trace
+    # can say which part of the fused step an op belongs to
+    with jax.named_scope("attn"):
+        x = rmsnorm(params["ln_attn"], h, cfg.norm_eps)
+        if cfg.mla is not None:
+            a, *planes = attn_mod.mla_step_paged_ragged(
+                params["attn"], cfg, x, planes[0], planes[1], block_table,
+                ctx_lens, q_lens)
+        elif len(planes) == 4:
+            a, *planes = attn_mod.attn_step_paged_ragged_q8(
+                params["attn"], cfg, x, planes[0], planes[1], planes[2],
+                planes[3], block_table, ctx_lens, q_lens)
+        else:
+            a, *planes = attn_mod.attn_step_paged_ragged(
+                params["attn"], cfg, x, planes[0], planes[1], block_table,
+                ctx_lens, q_lens)
+        h = h + rs * a
+    with jax.named_scope("mlp"):
+        x = rmsnorm(params["ln_ffn"], h, cfg.norm_eps)
+        f = _apply_block_ffn(params, cfg, x, ffn_kind, ep_axes)
+        return h + rs * f, tuple(planes)
 
 
 def step_ragged_block(params, cfg, h, cache, ctx_lens, q_lens, *,

@@ -562,8 +562,9 @@ class LM:
         new_cache = {"pos": ctx_lens + q_lens, "block_table": table}
         for n, p in zip(names, new_pools):
             new_cache["pool_" + n] = p
-        h = rmsnorm(params["final_ln"], h, cfg.norm_eps)
-        logits = self._logits(params, h)
+        with jax.named_scope("head"):
+            h = rmsnorm(params["final_ln"], h, cfg.norm_eps)
+            logits = self._logits(params, h)
         return logits, new_cache
 
     def _step_ragged_ssm(self, params, cache, tokens, ctx_lens, q_lens):

@@ -52,14 +52,16 @@ kernel (pooled) or the ragged dense step (mirrored) attends them all in
 the same launch with intra-chunk causal masking. Batch width and Qmax pad
 up a power-of-two bucketing ladder (padding rows carry ``q_len = 0`` and
 are masked end to end, including their pool scatters), so the jitted steps
-stop recompiling per width — ``step_compiles``/``step_cache_hits`` in
-``stats()`` pin it. ``ServeConfig.fuse_ticks=False`` keeps the
-batch=1-per-chunk baseline (``kvcache_bench``'s fused gate measures the
-gap), and model families without a cache descriptor (hybrid, encdec)
-fall back to it transparently.
+stop recompiling per width — the tracer's per-program compile counters
+(``compiles.jit(step_paged_ragged)`` in ``stats()``) pin it.
+``ServeConfig.fuse_ticks=False`` keeps the batch=1-per-chunk baseline
+(``kvcache_bench``'s fused gate measures the gap), and model families
+without a cache descriptor (hybrid, encdec) fall back to it
+transparently.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -71,6 +73,7 @@ from repro.core.clock import SimClock
 from repro.core.engines import EngineSpec, create_kv_engine
 from repro.core.kvcache import KVSpec
 from repro.serving import batching
+from repro.serving.trace import TRACER
 
 
 @dataclass
@@ -164,6 +167,9 @@ class Request:
     max_new: int
     generated: list = field(default_factory=list)
     done: bool = False
+    # when the request was made (``time.perf_counter_ns``): the scheduler's
+    # ``serve.queue`` span runs from here to its first admission
+    created_ns: int = field(default_factory=time.perf_counter_ns)
 
 
 class ServingEngine:
@@ -221,13 +227,11 @@ class ServingEngine:
             self._step_ragged = jax.jit(model.step_ragged)
             self._gather_new_kv_ragged = jax.jit(
                 batching.gather_new_kv_ragged, static_argnums=3)
-        # jit-shape ladder bookkeeping: every batched/fused step buckets its
-        # (path, batch-width, Qmax) to powers of two (pad + mask), and these
-        # counters pin that the jits stop recompiling per width
+        # launch counters; every batched/fused step buckets its batch width
+        # and Qmax to powers of two (pad + mask), and the tracer's
+        # per-program compile counters pin that the jits stop recompiling
         self.jit_stats = {"prefill_calls": 0, "step_calls": 0,
-                          "fused_steps": 0, "step_compiles": 0,
-                          "step_cache_hits": 0}
-        self._step_shapes: set = set()
+                          "fused_steps": 0}
         # ------------------------------------------- mirror-free pooled path
         self.max_pages = -(-cfg.max_len // cfg.page_tokens)
         budget = cfg.resolved_spec().kv_hbm_bytes
@@ -450,19 +454,6 @@ class ServingEngine:
         self.tiered.commit_prefill_planes(pools, rid, n)
         return {"pos": cache["pos"]}
 
-    def _count_step(self, path: str, width: int, qmax: int) -> None:
-        """Track jitted-step shape reuse. The power-of-two bucketing ladder
-        makes ``(path, width, qmax)`` a small fixed set, so after warmup
-        every step is a cache hit — ``step_compiles`` stops growing with
-        batch width / chunk size (pinned by tests/test_scheduler.py)."""
-        self.jit_stats["step_calls"] += 1
-        key = (path, width, qmax)
-        if key in self._step_shapes:
-            self.jit_stats["step_cache_hits"] += 1
-        else:
-            self._step_shapes.add(key)
-            self.jit_stats["step_compiles"] += 1
-
     def decode_batch(self, rids: list, caches: list, tokens: list,
                      mirrored: bool):
         """One batched single-token decode step over per-sequence cache
@@ -486,7 +477,7 @@ class ServingEngine:
         batch = batching.concat_rows(caches + [caches[0]] * pad)
         positions = batch["pos"]
         tok_arr = jnp.asarray(list(tokens) + [0] * pad, jnp.int32)[:, None]
-        self._count_step("decode", B + pad, 1)
+        self.jit_stats["step_calls"] += 1
         logits, batch = self._decode(self.params, batch, tok_arr, positions)
         self.mirror_decode_batch(rids if mirrored else [], batch,
                                  np.asarray(positions))
@@ -527,6 +518,7 @@ class ServingEngine:
         if not need:
             return committed
         args = np.asarray(jnp.argmax(logits[:B], axis=-1))   # (B, Qb)
+        TRACER.count("host_syncs")
         for i in need:
             q, s = q_lens[i], spec[i]
             acc = 0
@@ -578,6 +570,9 @@ class ServingEngine:
         qlen_j = jnp.asarray(qarr)
         if fused:       # the unfused pooled decode reuses this entry at
             self.jit_stats["fused_steps"] += 1   # q_len=1; don't count it
+            TRACER.count("rows.pad", Bb - B)
+            TRACER.count("slots.all", Bb * Qb)
+            TRACER.count("slots.pad", Bb * Qb - sum(q_lens))
 
         if self.pooled and not self.desc.has_pages:
             return self._step_state_batch(rids, caches, tok_rows, tok_j,
@@ -591,57 +586,66 @@ class ServingEngine:
             # tick pins them forever (the pool leak the regression test in
             # tests/test_tiering.py hunts)
             try:
-                tbl, ctx = self.tiered.prepare_step(rids, q_lens,
-                                                    self.max_pages)
-                model_pos = np.concatenate([np.asarray(c["pos"])
-                                            for c in caches])
-                if not np.array_equal(ctx, model_pos):
-                    raise RuntimeError(
-                        f"pool/table drift: engine lengths {ctx.tolist()} "
-                        f"!= model positions {model_pos.tolist()}")
-                tbl_p = np.zeros((Bb, self.max_pages), np.int32)
-                tbl_p[:B] = tbl
-                ctx_p = np.zeros(Bb, np.int32)
-                ctx_p[:B] = ctx
-                cache = {"block_table": jnp.asarray(tbl_p)}
-                for n, v in zip(names, self.tiered.pool_views()):
-                    cache["pool_" + n] = v
-                self._count_step("pool", Bb, Qb)
-                logits, out = self._step_paged_ragged(
-                    self.params, cache, tok_j, jnp.asarray(ctx_p), qlen_j)
-                committed = self._verify_drafts(logits, tok_rows, q_lens,
-                                                spec)
-                self.tiered.commit_step_planes(
-                    tuple(out["pool_" + n] for n in names), rids, committed,
-                    prepared=q_lens)
+                with TRACER.span("serve.prepare"):
+                    tbl, ctx = self.tiered.prepare_step(rids, q_lens,
+                                                        self.max_pages)
+                    model_pos = np.concatenate([np.asarray(c["pos"])
+                                                for c in caches])
+                    TRACER.count("host_syncs", B)
+                    if not np.array_equal(ctx, model_pos):
+                        raise RuntimeError(
+                            f"pool/table drift: engine lengths "
+                            f"{ctx.tolist()} != model positions "
+                            f"{model_pos.tolist()}")
+                    tbl_p = np.zeros((Bb, self.max_pages), np.int32)
+                    tbl_p[:B] = tbl
+                    ctx_p = np.zeros(Bb, np.int32)
+                    ctx_p[:B] = ctx
+                    cache = {"block_table": jnp.asarray(tbl_p)}
+                    for n, v in zip(names, self.tiered.pool_views()):
+                        cache["pool_" + n] = v
+                self.jit_stats["step_calls"] += 1
+                with TRACER.span("serve.launch"):
+                    logits, out = self._step_paged_ragged(
+                        self.params, cache, tok_j, jnp.asarray(ctx_p),
+                        qlen_j)
+                with TRACER.span("serve.commit"):
+                    committed = self._verify_drafts(logits, tok_rows,
+                                                    q_lens, spec)
+                    self.tiered.commit_step_planes(
+                        tuple(out["pool_" + n] for n in names), rids,
+                        committed, prepared=q_lens)
             except Exception:
                 self.tiered.abort_step(rids)
                 raise
-            new_rows = [
-                {"pos": out["pos"][i:i + 1]} if committed[i] == q_lens[i]
-                else {"pos": jnp.asarray([int(ctx[i]) + committed[i]],
-                                         jnp.int32)}
-                for i in range(B)]
-        else:
-            batch = batching.concat_rows(caches + [caches[0]] * (Bb - B))
-            ctx = batch["pos"]
-            self._count_step("mirror", Bb, Qb)
-            logits, nbatch = self._step_ragged(self.params, batch, tok_j,
-                                               ctx, qlen_j)
-            committed = self._verify_drafts(logits, tok_rows, q_lens, spec)
-            if mirrored:
-                self._mirror_step_ragged(rids, nbatch, ctx, q_lens, Qb,
-                                         committed)
-            nbatch = self._select_state_slots(nbatch, committed, B)
-            new_rows = [batching.split_row(nbatch, i) for i in range(B)]
-            ctx_np = np.asarray(ctx)
-            for i in range(B):
-                if committed[i] != q_lens[i]:
-                    # rewind past the rejected tail: its dense-cache KV is
-                    # masked (kv_pos > pos) and overwritten in place by the
-                    # row's next committed tokens
-                    new_rows[i]["pos"] = jnp.asarray(
-                        [int(ctx_np[i]) + committed[i]], jnp.int32)
+            with TRACER.span("serve.rows"):
+                new_rows = [
+                    {"pos": out["pos"][i:i + 1]} if committed[i] == q_lens[i]
+                    else {"pos": jnp.asarray([int(ctx[i]) + committed[i]],
+                                             jnp.int32)}
+                    for i in range(B)]
+                logit_rows = [logits[i:i + 1, :committed[i]]
+                              for i in range(B)]
+            return logit_rows, new_rows, committed
+        batch = batching.concat_rows(caches + [caches[0]] * (Bb - B))
+        ctx = batch["pos"]
+        self.jit_stats["step_calls"] += 1
+        logits, nbatch = self._step_ragged(self.params, batch, tok_j,
+                                           ctx, qlen_j)
+        committed = self._verify_drafts(logits, tok_rows, q_lens, spec)
+        if mirrored:
+            self._mirror_step_ragged(rids, nbatch, ctx, q_lens, Qb,
+                                     committed)
+        nbatch = self._select_state_slots(nbatch, committed, B)
+        new_rows = [batching.split_row(nbatch, i) for i in range(B)]
+        ctx_np = np.asarray(ctx)
+        for i in range(B):
+            if committed[i] != q_lens[i]:
+                # rewind past the rejected tail: its dense-cache KV is
+                # masked (kv_pos > pos) and overwritten in place by the
+                # row's next committed tokens
+                new_rows[i]["pos"] = jnp.asarray(
+                    [int(ctx_np[i]) + committed[i]], jnp.int32)
         logit_rows = [logits[i:i + 1, :committed[i]] for i in range(B)]
         return logit_rows, new_rows, committed
 
@@ -657,6 +661,7 @@ class ServingEngine:
         Zero device→host bytes, same as the paged branch."""
         B = len(rids)
         ctx = np.concatenate([np.asarray(c["pos"]) for c in caches])
+        TRACER.count("host_syncs", B)
         eng_len = [int(self.tiered.seq_len.get(r, 0)) for r in rids]
         if eng_len != [int(c) for c in ctx]:
             raise RuntimeError(
@@ -668,7 +673,7 @@ class ServingEngine:
         # q_len = 0, so their outputs are discarded and nothing commits
         views = self.tiered.state_views(list(rids) + [rids[0]] * (Bb - B))
         cache = {p.name: v for p, v in zip(self.desc.seq_planes, views)}
-        self._count_step("pool", Bb, Qb)
+        self.jit_stats["step_calls"] += 1
         logits, out = self._step_paged_ragged(
             self.params, cache, tok_j, jnp.asarray(ctx_p), qlen_j)
         committed = self._verify_drafts(logits, tok_rows, q_lens, spec)
@@ -724,7 +729,7 @@ class ServingEngine:
             for p, v in zip(self.desc.seq_planes, views):
                 pc[p.name] = v
             for t in toks:
-                self._count_step("pool-chunk1", 1, 1)
+                self.jit_stats["step_calls"] += 1
                 logits, pc = self._decode(
                     self.params, pc, jnp.asarray([[int(t)]], jnp.int32),
                     pc["pos"])
@@ -740,7 +745,7 @@ class ServingEngine:
                       "block_table": jnp.asarray(tbl)}
                 for n, v in zip(names, self.tiered.pool_views()):
                     pc["pool_" + n] = v
-                self._count_step("pool-chunk1", 1, 1)
+                self.jit_stats["step_calls"] += 1
                 logits, out = self._decode_paged(
                     self.params, pc, jnp.asarray([[int(t)]], jnp.int32),
                     cache["pos"])
@@ -749,7 +754,7 @@ class ServingEngine:
                 cache = {"pos": out["pos"]}
             return logits, cache
         for t in toks:
-            self._count_step("mirror-chunk1", 1, 1)
+            self.jit_stats["step_calls"] += 1
             logits, cache = self._decode(
                 self.params, cache, jnp.asarray([[int(t)]], jnp.int32),
                 cache["pos"])
@@ -828,8 +833,10 @@ class ServingEngine:
         return requests
 
     def stats(self) -> dict:
+        """The engine's counters, its last scheduler's, the journal's, the
+        KV engine's and the process tracer's (``serve/trace.py``)."""
         journal = {} if self.journal is None else dict(self.journal.stats)
         return {"sim_time_s": self.clock.now,
                 "mirror_d2h_bytes": self.mirror_d2h_bytes,
                 **self.jit_stats, **self.spec_stats, **self.sched_stats,
-                **journal, **self.tiered.stats}
+                **journal, **self.tiered.stats, **TRACER.counters()}

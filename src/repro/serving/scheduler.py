@@ -68,6 +68,7 @@ preemption schedule (``tests/test_scheduler.py`` locks this down).
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Optional
@@ -77,6 +78,7 @@ import numpy as np
 
 from repro.serving import batching
 from repro.serving.faults import CrashFault, LostPageError
+from repro.serving.trace import TRACER
 
 if TYPE_CHECKING:                      # engine.py imports us for generate()
     from repro.serving.engine import Request, ServingEngine
@@ -118,7 +120,6 @@ class SchedulerStats:
     admitted: int = 0
     finished: int = 0
     preempts: int = 0
-    restores: int = 0
     peak_running: int = 0
     prefill_chunks: int = 0            # chunk-continuation rows stepped
     fused_ticks: int = 0               # ticks run as ONE mixed ragged step
@@ -163,19 +164,22 @@ class Scheduler:
     def _batch_tokens(self) -> int:
         return sum(r.length for r in self.running)
 
-    def _has_room(self, cand_tokens: int) -> bool:
+    def _refusal(self, cand_tokens: int) -> Optional[str]:
+        """Why a candidate of ``cand_tokens`` cannot be admitted now — the
+        first test that refuses it (``seqs``, ``pressure``, ``pages`` or
+        ``tokens``) — or None when there is room."""
         if len(self.running) >= self.max_batch_seqs:
-            return False
+            return "seqs"
         if not self.running:
-            return True                # force progress: never deadlock
+            return None                # force progress: never deadlock
         if self.engine.tiered.pressure() >= 1.0:
-            return False               # admitting now would preempt someone
+            return "pressure"          # admitting now would preempt someone
         if not self.engine.tiered.can_admit_tokens(cand_tokens):
-            return False               # pooled: no pages to place it
+            return "pages"             # pooled: no pages to place it
         if self.max_batch_tokens is not None and \
                 self._batch_tokens() + cand_tokens > self.max_batch_tokens:
-            return False
-        return True
+            return "tokens"
+        return None
 
     def _first_chunk(self, prompt_len: int) -> int:
         """Tokens the admission prefill processes (the rest rides as the
@@ -202,57 +206,72 @@ class Scheduler:
         # row whose chunk cannot be placed would bounce it straight back
         # through the fused tick's tight-pool guard (restore/preempt churn
         # with no progress)
-        while self.preempted and self._has_room(
+        while self.preempted and self._refusal(
                 self.preempted[0].length + (
                     self._chunk_len(self.preempted[0].pending)
                     if self.preempted[0].pending is not None
-                    and len(self.preempted[0].pending) else 1)):
+                    and len(self.preempted[0].pending) else 1)) is None:
             pre = self.preempted.popleft()
-            if pre.mirrored:
-                self.engine.tiered.restore(pre.req.rid)
-            self.running.append(_Running(
-                req=pre.req, cache=batching.row_to_device(pre.cache),
-                logits=(None if pre.logits is None
-                        else jnp.asarray(pre.logits)), length=pre.length,
-                mirrored=pre.mirrored, admitted_tick=self.stats.ticks,
-                pending=pre.pending, stalled_ticks=pre.stalled_ticks))
-            self.stats.restores += 1
-        while self.waiting and self._has_room(
-                self._first_chunk(len(self._full_prompt(self.waiting[0])))
-                + 1):
-            req = self.waiting.popleft()
-            # effective prompt: a shed or crash-recovered row re-prefills
-            # its prompt PLUS its already-committed tokens (ISSUE 10) —
-            # greedy decode then resumes exactly where the committed
-            # stream left off, so degradation never diverges tokens
-            full = self._full_prompt(req)
-            # prefix-cache splice (ISSUE 6): a cached prefix admits as a
-            # block-table alias — no prefill launch for the covered tokens;
-            # the uncovered tail rides as the row's pending chunk tail and
-            # its first chunk pass produces the row's first logits
-            spliced = (self.engine.admit_prefix(req)
-                       if not req.generated else None)
-            if spliced is not None:
-                cache, covered = spliced
+            with TRACER.span("serve.restore", pre.req.rid):
+                if pre.mirrored:
+                    self.engine.tiered.restore(pre.req.rid)
                 self.running.append(_Running(
-                    req=req, cache=cache, logits=None, length=covered,
-                    mirrored=True, admitted_tick=self.stats.ticks,
-                    pending=req.prompt[covered:]))
-                self.stats.admitted += 1
-                self.stats.spliced += 1
-                continue
-            first = self._first_chunk(len(full))
-            logits, cache = self.engine.prefill_one(req, first, tokens=full)
-            pending = full[first:] if first < len(full) else None
-            self.running.append(_Running(
-                req=req, cache=cache, logits=logits, length=first,
-                mirrored="k" in cache or self.engine.pooled,
-                admitted_tick=self.stats.ticks, pending=pending))
-            if pending is None:
-                self.engine.on_prompt_complete(req.rid, full)
-            self.stats.admitted += 1
+                    req=pre.req, cache=batching.row_to_device(pre.cache),
+                    logits=(None if pre.logits is None
+                            else jnp.asarray(pre.logits)),
+                    length=pre.length, mirrored=pre.mirrored,
+                    admitted_tick=self.stats.ticks, pending=pre.pending,
+                    stalled_ticks=pre.stalled_ticks))
+        while self.waiting:
+            refused = self._refusal(self._first_chunk(
+                len(self._full_prompt(self.waiting[0]))) + 1)
+            if refused is not None:
+                TRACER.count("admit_blocked." + refused)
+                break
+            req = self.waiting.popleft()
+            if not req.generated:
+                # its wait from creation to this first admission (a row
+                # shed before its first token records a second, longer one)
+                TRACER.record("serve.queue", req.created_ns,
+                              time.perf_counter_ns(), req.rid, parent=0)
+            with TRACER.span("serve.admit", req.rid):
+                self._admit_one(req)
         self.stats.peak_running = max(self.stats.peak_running,
                                       len(self.running))
+
+    def _admit_one(self, req: "Request") -> None:
+        """Admit one request from ``waiting``: a prefix-cache splice, else
+        its admission prefill."""
+        # effective prompt: a shed or crash-recovered row re-prefills its
+        # prompt PLUS its already-committed tokens — greedy decode then
+        # resumes exactly where the committed stream left off, so
+        # degradation never diverges tokens
+        full = self._full_prompt(req)
+        # prefix-cache splice: a cached prefix admits as a
+        # block-table alias — no prefill launch for the covered tokens; the
+        # uncovered tail rides as the row's pending chunk tail and its
+        # first chunk pass produces the row's first logits
+        spliced = (self.engine.admit_prefix(req)
+                   if not req.generated else None)
+        if spliced is not None:
+            cache, covered = spliced
+            self.running.append(_Running(
+                req=req, cache=cache, logits=None, length=covered,
+                mirrored=True, admitted_tick=self.stats.ticks,
+                pending=req.prompt[covered:]))
+            self.stats.admitted += 1
+            self.stats.spliced += 1
+            return
+        first = self._first_chunk(len(full))
+        logits, cache = self.engine.prefill_one(req, first, tokens=full)
+        pending = full[first:] if first < len(full) else None
+        self.running.append(_Running(
+            req=req, cache=cache, logits=logits, length=first,
+            mirrored="k" in cache or self.engine.pooled,
+            admitted_tick=self.stats.ticks, pending=pending))
+        if pending is None:
+            self.engine.on_prompt_complete(req.rid, full)
+        self.stats.admitted += 1
 
     # ------------------------------------------------------------------ step
     def _chunk_len(self, pending) -> int:
@@ -289,6 +308,7 @@ class Scheduler:
         tokens = []
         for r in rows:
             nxt = int(jnp.argmax(r.logits[:, -1], -1)[0])
+            TRACER.count("host_syncs")
             r.req.generated.append(nxt)
             tokens.append(nxt)
             self.stats.decode_rows += 1
@@ -319,6 +339,7 @@ class Scheduler:
         token — all derivable state, so preemption needs no proposer
         hooks."""
         nxt = int(jnp.argmax(r.logits[:, -1], -1)[0])
+        TRACER.count("host_syncs")
         drafts: list = []
         if k:
             room = min(self.engine.cfg.max_len - (r.length + 1),
@@ -342,15 +363,23 @@ class Scheduler:
         one-shot prefill would have left it; a speculative row comes out
         holding its last ACCEPTED slot's logits, exactly as sequential
         decode would after the same tokens."""
-        for r in self.running:
-            if r.pending is not None and not len(r.pending):
-                r.pending = None
-        # plan every decode row's tokens up front so the tight-pool guard
-        # below sheds against the true per-row slot counts (1 + drafts),
-        # not an assumed single token
-        k = self.engine.speculate_k
-        plan = {r.req.rid: self._plan_decode(r, k)
-                for r in self.running if r.pending is None}
+        with TRACER.span("serve.plan"):
+            for r in self.running:
+                if r.pending is not None and not len(r.pending):
+                    r.pending = None
+            # plan every decode row's tokens up front so the tight-pool
+            # guard sheds against the true per-row slot counts (1 +
+            # drafts), not an assumed single token
+            k = self.engine.speculate_k
+            plan = {r.req.rid: self._plan_decode(r, k)
+                    for r in self.running if r.pending is None}
+        with TRACER.span("serve.step"):
+            self._step_planned(plan)
+
+    def _step_planned(self, plan: dict) -> None:
+        """The fused step after planning: shed rows the pool cannot place,
+        build the rows, run :meth:`ServingEngine.step_batch`, advance every
+        row by what it committed."""
         # tight-pool guard: prepare_step pins every batch row while it
         # allocates chunk pages, so a pool that cannot place this tick's
         # chunks with the whole batch pinned must shed a row FIRST —
@@ -375,6 +404,7 @@ class Scheduler:
                 spec.append(0)
                 appended.append(0)
                 self.stats.prefill_chunks += 1
+                TRACER.count("rows.chunk")
             else:
                 nxt, drafts = plan[r.req.rid]
                 r.req.generated.append(nxt)
@@ -383,6 +413,7 @@ class Scheduler:
                 spec.append(len(drafts))
                 appended.append(1)
                 self.stats.decode_rows += 1
+                TRACER.count("rows.decode")
         try:
             logits, caches, committed = self.engine.step_batch(
                 [r.req.rid for r in rows], [r.cache for r in rows], toks,
@@ -478,16 +509,20 @@ class Scheduler:
 
     def _preempt_one(self) -> None:
         victim = self._pick_victim()
-        self.running.remove(victim)
-        if victim.mirrored:
-            self.engine.tiered.preempt(victim.req.rid)
-        self.preempted.append(_Preempted(
-            req=victim.req, cache=batching.row_to_host(victim.cache),
-            logits=(None if victim.logits is None
-                    else np.asarray(victim.logits)), length=victim.length,
-            mirrored=victim.mirrored, pending=victim.pending,
-            stalled_ticks=victim.stalled_ticks))
-        self.stats.preempts += 1
+        with TRACER.span("serve.preempt", victim.req.rid):
+            self.running.remove(victim)
+            if victim.mirrored:
+                self.engine.tiered.preempt(victim.req.rid)
+            # the row's arrays and its logits cross to the host
+            TRACER.count("host_syncs", len(victim.cache)
+                         + (victim.logits is not None))
+            self.preempted.append(_Preempted(
+                req=victim.req, cache=batching.row_to_host(victim.cache),
+                logits=(None if victim.logits is None
+                        else np.asarray(victim.logits)),
+                length=victim.length, mirrored=victim.mirrored,
+                pending=victim.pending, stalled_ticks=victim.stalled_ticks))
+            self.stats.preempts += 1
 
     def _preempt_under_pressure(self) -> None:
         while self._over_budget() and \
@@ -530,7 +565,14 @@ class Scheduler:
         tick's committed tokens append to the journal BEFORE a scripted
         crash fires, so every durable tick is replayable — a crash placed
         before the append would simply lose that tick's tokens and
-        recovery would re-decode them identically."""
+        recovery would re-decode them identically.
+
+        Every tick is a ``serve.tick`` span, its phases spans under it
+        (``serve/trace.py``)."""
+        with TRACER.span("serve.tick"):
+            return self._tick()
+
+    def _tick(self) -> bool:
         self._admit()
         self._finish_done()    # max_new=0 rows retire without decoding
         if not self.running:
@@ -556,24 +598,27 @@ class Scheduler:
         except LostPageError as e:
             self._shed_seq(e.seq)
             shed = e
-        if self.engine.journal is not None:
-            commits = [(r.req.rid, gen_before[r.req.rid],
-                        r.req.generated[gen_before[r.req.rid]:])
-                       for r in self.running
-                       if r.req.rid in gen_before
-                       and len(r.req.generated) > gen_before[r.req.rid]]
-            if commits:
-                self.engine.journal.append_tick(self.stats.ticks, commits)
-        if self.engine.degraded():
-            self.stats.degraded_ticks += 1
-        self._finish_done()
-        self._preempt_under_pressure()
-        if shed is None:
-            # a shed tick made no progress by design (the injected loss
-            # aborted the whole step) — that is degradation, not the
-            # starvation class the progress guard hunts
-            self._check_progress(lengths_before)
-        self._publish_plan()
+        with TRACER.span("serve.retire"):
+            if self.engine.journal is not None:
+                commits = [(r.req.rid, gen_before[r.req.rid],
+                            r.req.generated[gen_before[r.req.rid]:])
+                           for r in self.running
+                           if r.req.rid in gen_before
+                           and len(r.req.generated) > gen_before[r.req.rid]]
+                if commits:
+                    self.engine.journal.append_tick(self.stats.ticks,
+                                                    commits)
+            if self.engine.degraded():
+                self.stats.degraded_ticks += 1
+            self._finish_done()
+            self._preempt_under_pressure()
+            if shed is None:
+                # a shed tick made no progress by design (the injected loss
+                # aborted the whole step) — that is degradation, not the
+                # starvation class the progress guard hunts
+                self._check_progress(lengths_before)
+        with TRACER.span("serve.publish"):
+            self._publish_plan()
         if inj is not None and inj.crash_now(self.stats.ticks):
             raise CrashFault(self.stats.ticks)
         return bool(self.waiting or self.running or self.preempted)

@@ -30,3 +30,11 @@ else:
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(autouse=True)
+def _fresh_tracer():
+    """Each test starts with an empty process tracer (serving/trace.py)."""
+    from repro.serving.trace import TRACER
+    TRACER.reset()
+    yield
