@@ -1,14 +1,14 @@
 """Paged attention Pallas TPU kernels.
 
 The paging design's on-device read path (DESIGN.md §2a): the KV cache lives
-as fixed-size token pages in a physical pool; the block table is
-scalar-prefetched (SMEM) and drives the BlockSpec index maps, so each grid
-step DMAs exactly one page into VMEM — block-table indirection *inside* the
-kernel, the TPU analogue of NVPages' radix-tree → page pointer walk.
+as fixed-size token pages in a physical pool left in HBM, and the block
+table, scalar-prefetched into SMEM, drives the kernel's own page copies —
+block-table indirection *inside* the kernel, the TPU analogue of NVPages'
+radix-tree → page pointer walk.
 
 One kernel body serves every dense-GQA entry: ``_pa_ragged_kernel`` over
-grid ``(B, MP)`` (single layer) or ``(L, B, MP)`` (multi-layer), with
-``(k, v)`` pools or int8 ``(k, v)`` pools plus their scale planes.
+grid ``(B,)`` (single layer) or ``(L, B)`` (multi-layer), one grid step per
+row, with ``(k, v)`` pools or int8 ``(k, v)`` pools plus their scale planes.
 
 * ``paged_attention_ragged_pallas`` / ``paged_attention_layers_ragged_pallas``
   — each row carries a block of up to ``Qmax`` new-token queries
@@ -24,110 +24,304 @@ grid ``(B, MP)`` (single layer) or ``(L, B, MP)`` (multi-layer), with
   token per row: the ``q_len == 1`` slice of the ragged entries, so the two
   are bitwise identical by construction.
 
-**Block layout.** A page block holds ALL KV heads, ``(1, T, K, D)`` over the
-``(P, T, K, D)`` pool (and ``(1, T, K)`` over a ``(P, T, K)`` scale plane),
-and the body loops over the K heads statically. Mosaic requires the last two
-dims of a block to be multiples of (8, 128) or the full array dims; a
-one-head ``(1, T, 1, D)`` block is neither, and the TPU compiler refuses it.
-Queries ride as one ``(K, Qmax*G, D)`` block per row, so VMEM use grows as
-``K * Qmax * G * D``: the f32 accumulator at K=8, G=2, D=128, Qmax=256 is
-2 MiB.
+**Grid and tiles.** A grid step holds one row's query block
+``(K, Qmax*G, D)`` (query ``i``, group member ``g`` at row ``i*G + g``) and
+walks only the row's live work, in one loop over (query tile, KV block)
+pairs:
 
-Online-softmax state lives in VMEM scratch across the page axis. Pages past
-``lengths[b]`` are skipped with ``pl.when`` (their index maps clamp to a
-valid page and the body is skipped). A row with ``lengths[b] == 0`` never
-runs the compute body, so its output is exactly zero — the refs mirror that
-contract.
+* a query tile is ``tq`` rows of the query block; tiles wholly past
+  ``q_lens[b] * G`` are never visited;
+* a KV block is ``ppb`` consecutive entries of the row's block table, so
+  ``ppb * T`` tokens; tile ``t`` visits the blocks up to the causal horizon
+  of its last live query and no further.
+
+``block_sizes`` derives both from the launch's shapes: ``tq`` is
+``Qmax*G`` up to 128 rows (the query block is padded to a whole number of
+tiles), ``ppb`` the pages of 256 tokens, at most the table's width and what
+keeps the double-buffered k and v blocks within 4 MiB.
+``ragged_grid_blocks`` counts, with the same sizes, the pairs a launch
+holds and the pairs it visits.
+
+**KV copies.** The k and v pools stay in HBM. A block is one async copy per
+live page and pool into a two-slot VMEM buffer ``(2, ppb, T, K, D)``; the
+next pair's block — or, on a row's last pair, the first block of the next
+grid step's row — is copied while the current one computes. Pages past a
+row's length are not copied: the buffers are zeroed at the first grid
+step, so such a slot holds zeros or an earlier page, finite either way,
+and masked. A ``(T, K)`` page of an int8 pool's scale plane is not whole
+(8, 128) tiles, which a copy out of HBM needs; so the launcher gathers each
+row's scales by its table, tokens along lanes, ``(K, tokens)``, and the
+pipeline brings them in with the row's query block.
+
+**VMEM.** The KV buffers take ``2 * ppb`` pages of k and of v: 2 MiB at
+InternLM2-1.8B widths (T=16, K=8, D=128, bf16: ppb=16). A query tile's
+online-softmax state, per head ``m`` and ``l`` ``(tq, 1)`` and ``acc``
+``(tq, D)`` in f32, takes 1.5 MiB at tq=128 and K=8 (``m`` and ``l`` pad
+to 128 lanes). The pipeline double-buffers the row's query and output
+blocks: 4 MiB at Qmax=256 in bf16.
+
+**Numerics.** Q·Kᵀ takes q and the pages straight into the MXU when they
+share a dtype (products of bf16 values are exact in f32, which
+accumulates). int8 values are exact in q's dtype, so int8 pages meet q
+there too; a token's k scale then multiplies its scores and its v scale
+its probabilities before P·V. Scores, the online-softmax state, the
+probabilities and P·V are f32.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+Q_TILE_ROWS = 128           # query rows (queries × group) of a tile, at most
+KV_BLOCK_TOKENS = 256       # tokens of a KV block, at most
+KV_VMEM_BYTES = 4 << 20     # both slots of the k and v blocks, at most
 
 
-def _ragged_softmax_step(s, m_ref, l_ref, acc_ref, v, h=Ellipsis):
+def block_sizes(q_rows: int, page_tokens: int, max_pages: int,
+                page_bytes: int) -> tuple:
+    """``(tq, ppb)``: the query rows of a tile and the pages of a KV block,
+    for rows of ``q_rows`` query rows (``Qmax * G``) over a block table
+    ``max_pages`` wide, whose pages take ``page_bytes`` of k and v."""
+    tq = min(q_rows, Q_TILE_ROWS)
+    ppb = max(1, min(max_pages, KV_BLOCK_TOKENS // page_tokens,
+                     KV_VMEM_BYTES // (2 * page_bytes)))
+    return tq, ppb
+
+
+def ragged_grid_blocks(q_lens, lengths, *, qmax: int, group: int,
+                       page_tokens: int, max_pages: int,
+                       page_bytes: int) -> tuple:
+    """``(all, live)`` (query tile × KV block) pairs of one layer's launch:
+    those its grid holds (every row, tile and block of the table) and those
+    it visits, for rows of ``q_lens`` new queries over ``lengths`` pooled
+    tokens (the new ones included), by the kernel's own ``block_sizes``."""
+    q_rows = qmax * group
+    tq, ppb = block_sizes(q_rows, page_tokens, max_pages, page_bytes)
+    n_tiles, n_blocks = -(-q_rows // tq), -(-max_pages // ppb)
+    q = np.asarray(q_lens, np.int64)[:, None]
+    ln = np.asarray(lengths, np.int64)[:, None]
+    t = np.arange(n_tiles)[None, :]
+    last = np.minimum(q - 1, ((t + 1) * tq - 1) // group)
+    blocks = np.minimum(-(-(ln - q + last + 1) // (ppb * page_tokens)),
+                        n_blocks)
+    visited = (t * tq < q * group) & (ln > 0)
+    return (q.shape[0] * n_tiles * n_blocks,
+            int(np.where(visited, blocks, 0).sum()))
+
+
+def _ragged_softmax_step(s, m_ref, l_ref, acc_ref, v, v_scale=None):
     """One online-softmax update over a (QG, T) score block whose rows past
-    ``q_len`` (query padding) are fully masked; ``h`` selects one KV head's
-    slice of the scratch. Masked probabilities are zeroed explicitly: a
+    ``q_len`` (query padding) are fully masked, into one head's state refs;
+    ``v_scale`` (1, T), where given, scales each token's probability before
+    P·V (int8 values). Masked probabilities are zeroed explicitly: a
     fully-masked row's running max stays NEG_INF and ``exp(s - m)`` would
-    otherwise evaluate to exp(0) = 1 garbage."""
-    m_prev = m_ref[h]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    pr = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - m_new), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[h] = l_ref[h] * corr + jnp.sum(pr, axis=1, keepdims=True)
-    acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-        pr, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[h] = m_new
+    otherwise evaluate to exp(0) = 1 garbage. Written in lax primitives
+    (see ``_pa_ragged_kernel``)."""
+    def cols(x, shape):                     # (rows, 1) across ``shape``
+        return lax.broadcast_in_dim(x, shape, (0, 1))
+
+    m_prev = m_ref[...]
+    m_new = lax.max(m_prev, lax.expand_dims(lax.reduce_max(s, (1,)), (1,)))
+    pr = lax.select(lax.gt(s, np.float32(NEG_INF * 0.5)),
+                    lax.exp(lax.sub(s, cols(m_new, s.shape))),
+                    lax.broadcast(np.float32(0), s.shape))
+    corr = lax.exp(lax.sub(m_prev, m_new))
+    l_ref[...] = lax.add(lax.mul(l_ref[...], corr),
+                         lax.expand_dims(lax.reduce_sum(pr, (1,)), (1,)))
+    if v_scale is not None:
+        pr = lax.mul(pr, lax.broadcast_in_dim(v_scale, pr.shape, (0, 1)))
+    acc = acc_ref[...]
+    acc_ref[...] = lax.add(
+        lax.mul(acc, cols(corr, acc.shape)),
+        lax.dot_general(pr, v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
+    m_ref[...] = m_new
 
 
-def _pa_ragged_kernel(table_ref, len_ref, qlen_ref, q_ref, k_ref, v_ref,
-                      *refs, scale: float, page_tokens: int, group: int,
-                      batch_axis: int):
-    """Ragged-query body for dense and int8 pools. ``refs`` is
-    ``(o, m, l, acc)`` for dense pools and ``(ks, vs, o, m, l, acc)`` for
-    int8 pools, whose per-(token, head) scales dequantize each page in
-    fp32 — numerically the ``dequantize_kv`` grid, never materialized in
-    HBM. The single-layer entries run it with ``batch_axis=0`` over grid
-    (B, MP), the multi-layer ones with ``batch_axis=1`` over (L, B, MP):
-    the layer axis shifts the program ids and adds a leading unit dim to
-    every block."""
-    if len(refs) == 6:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = refs
-    b = pl.program_id(batch_axis)
-    p = pl.program_id(batch_axis + 1)
-    last_p = pl.num_programs(batch_axis + 1) - 1
-    length = len_ref[b]
-    q_len = qlen_ref[b]
-    lead = (0,) * (batch_axis + 1)          # the blocks' leading unit dims
+def _pa_ragged_kernel(table_ref, len_ref, qlen_ref, q_ref, *refs,
+                      scale: float, group: int, tq: int, batch_axis: int):
+    """Ragged-query body for dense and int8 pools, one grid step per row.
+    ``refs`` holds the k and v pools (in HBM), for int8 pools the row's
+    k and v scales ``(K, tokens)`` (VMEM blocks), the output block, the two
+    two-slot KV buffers, the DMA semaphores, each head's online-softmax
+    state ``m``, ``l``, ``acc`` and an SMEM pair ``(first block in flight,
+    its slot)`` carried from one grid step to the next. The single-layer
+    entries run it with ``batch_axis=0`` over grid (B,), the multi-layer
+    ones with ``batch_axis=1`` over (L, B), the layer indexing the pools.
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    The body is traced again for every program the kernel is compiled
+    into, so it is written to trace cheaply: lax primitives with numpy
+    scalars rather than ``jnp`` calls (each a nested jit) and Python
+    scalars (each an eager device constant), and refs indexed with slices
+    and traced values only."""
+    K, _, D = q_ref.shape
+    pools, scales = refs[:2], refs[2:len(refs) - 5 - 3 * K]
+    o_ref, kbuf, vbuf, sem, *heads, state = refs[len(refs) - 5 - 3 * K:]
+    m_refs, l_refs, acc_refs = heads[:K], heads[K:2 * K], heads[2 * K:]
+    i32, f32 = np.int32, np.float32
+    ids = [pl.program_id(i) for i in range(batch_axis + 1)]
+    layer, b = (ids[0] if batch_axis else None), ids[-1]
+    ppb, T = kbuf.shape[1:3]
+    bk = ppb * T
+    max_pages = table_ref.shape[1]
+    n_blocks = -(-max_pages // ppb)
+    whole_row = q_ref.shape[1] == tq        # one tile: its offset is static
 
-    live = ((p * page_tokens) < length) & (q_len > 0)
+    def live(row):
+        return lax.bitwise_and(lax.gt(qlen_ref[row], i32(0)),
+                               lax.gt(len_ref[row], i32(0)))
 
-    @pl.when(live)
-    def _compute():
-        K, QG, _ = acc_ref.shape
-        kp = k_ref[lead].astype(jnp.float32)                 # (T, K, D)
-        vp = v_ref[lead].astype(jnp.float32)
-        if ks_ref is not None:
-            ks = ks_ref[lead].astype(jnp.float32)            # (T, K)
-            vs = vs_ref[lead].astype(jnp.float32)
-        pos = p * page_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, (QG, page_tokens), 1)
-        qi = jax.lax.broadcasted_iota(jnp.int32, (QG, page_tokens), 0) // group
-        # query i sits at absolute position length - q_len + i: causal
-        # within the chunk against the pool; padding query slots masked out
-        allow = (pos <= (length - q_len + qi)) & (qi < q_len)
+    def cdiv(x, d):                         # x >= 0
+        return lax.div(lax.add(x, i32(d - 1)), i32(d))
+
+    def block_copies(lyr, row, blk, slot, start):
+        """Start (or wait for) the copies of block ``blk`` of ``row`` into
+        ``slot``: one per live page and pool."""
+        first = lax.mul(blk, i32(ppb))
+        pages = lax.sub(cdiv(lax.min(len_ref[row], i32(max_pages * T)), T),
+                        first)
+
+        def copy(i, carry):
+            page = table_ref[row, lax.add(first, i)]
+            at = (page,) if lyr is None else (lyr, page)
+            for src, buf in zip(pools, (kbuf, vbuf)):
+                c = pltpu.make_async_copy(src.at[at], buf.at[slot, i],
+                                          sem.at[slot])
+                if start:
+                    c.start()
+                else:
+                    c.wait()
+            return carry
+
+        lax.fori_loop(i32(0), lax.min(pages, i32(ppb)), copy, i32(0))
+
+    @pl.when(sum(ids) == 0)
+    def _first_step():
+        state[0] = i32(0)
+        kbuf[...] = lax.broadcast(np.zeros((), kbuf.dtype), kbuf.shape)
+        vbuf[...] = lax.broadcast(np.zeros((), vbuf.dtype), vbuf.shape)
+
+    # the next grid step's row, whose first block this row's last pair
+    # prefetches when that row is live
+    wrap = lax.eq(lax.add(b, i32(1)), pl.num_programs(batch_axis))
+    nxt_row = lax.select(wrap, i32(0), lax.add(b, i32(1)))
+    nxt_layer, has_next = None, lax.bitwise_not(wrap)
+    if batch_axis:
+        nxt_layer = lax.select(wrap, lax.add(layer, i32(1)), layer)
+        has_next = lax.bitwise_or(has_next, lax.lt(lax.add(layer, i32(1)),
+                                                   pl.num_programs(0)))
+    prefetch_next = lax.bitwise_and(has_next, live(nxt_row))
+
+    length, q_len = len_ref[b], qlen_ref[b]
+    start = lax.sub(length, q_len)          # position of the row's query 0
+    n_tiles = lax.select(live(b), cdiv(lax.mul(q_len, i32(group)), tq),
+                         i32(0))
+    o_ref[...] = lax.broadcast(np.zeros((), o_ref.dtype), o_ref.shape)
+    rows_i = lax.broadcasted_iota(jnp.int32, (tq, bk), 0)
+    cols_i = lax.broadcasted_iota(jnp.int32, (tq, bk), 1)
+
+    def tile_blocks(t):
+        """KV blocks tile ``t`` needs: up to its last live query's
+        horizon."""
+        last = lax.min(lax.sub(q_len, i32(1)),
+                       lax.div(lax.sub(lax.mul(lax.add(t, i32(1)), i32(tq)),
+                                       i32(1)), i32(group)))
+        return lax.min(cdiv(lax.add(start, lax.add(last, i32(1))), bk),
+                       i32(n_blocks))
+
+    def pair(carry):
+        t, j, slot = carry
+        other = lax.sub(i32(1), slot)
+        tile_done = lax.ge(lax.add(j, i32(1)), tile_blocks(t))
+        t2 = lax.select(tile_done, lax.add(t, i32(1)), t)
+        j2 = lax.select(tile_done, i32(0), lax.add(j, i32(1)))
+
+        # the next pair's block, or on the row's last pair the next row's
+        # first block, into the other slot
+        own = lax.lt(t2, n_tiles)
+
+        @pl.when(lax.bitwise_or(own, prefetch_next))
+        def _prefetch():
+            block_copies(None if layer is None else
+                         lax.select(own, layer, nxt_layer),
+                         lax.select(own, b, nxt_row),
+                         lax.select(own, j2, i32(0)), other, True)
+
+        @pl.when(lax.bitwise_and(lax.bitwise_not(own), prefetch_next))
+        def _hand_over():
+            state[0] = i32(1)
+            state[1] = other
+
+        block_copies(layer, b, j, slot, False)
+        r0 = 0 if whole_row else pl.multiple_of(lax.mul(t, i32(tq)), tq)
+
+        @pl.when(lax.eq(j, i32(0)))
+        def _init():
+            lowest = lax.broadcast(f32(NEG_INF), m_refs[0].shape)
+            zero = lax.broadcast(f32(0), l_refs[0].shape)
+            zeros = lax.broadcast(f32(0), acc_refs[0].shape)
+            for m_ref, l_ref, acc_ref in zip(m_refs, l_refs, acc_refs):
+                m_ref[...], l_ref[...], acc_ref[...] = lowest, zero, zeros
+
+        # query i sits at absolute position start + i: causal within the
+        # chunk against the pool; padding query slots masked out
+        qi = lax.div(lax.add(rows_i, r0), i32(group))
+        pos = lax.add(cols_i, lax.mul(j, i32(bk)))
+        allow = lax.bitwise_and(lax.le(pos, lax.add(qi, start)),
+                                lax.lt(qi, q_len))
+        masked = lax.broadcast(f32(NEG_INF), (tq, bk))
         for h in range(K):
-            q = q_ref[lead + (h,)].astype(jnp.float32)       # (QG, D)
-            k, v = kp[:, h, :], vp[:, h, :]                  # (T, D)
-            if ks_ref is not None:
-                k = k * ks[:, h:h + 1]
-                v = v * vs[:, h:h + 1]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            s = jnp.where(allow, s * scale, NEG_INF)
-            _ragged_softmax_step(s, m_ref, l_ref, acc_ref, v, h)
+            hs = pl.ds(h, 1)
+            q = lax.reshape(q_ref[hs, pl.ds(r0, tq), :], (tq, D))
+            k = lax.reshape(kbuf[slot, :, :, hs, :], (bk, D))
+            v = lax.convert_element_type(
+                lax.reshape(vbuf[slot, :, :, hs, :], (bk, D)), jnp.float32)
+            if scales:              # int8 values are exact in q's dtype
+                k = lax.convert_element_type(k, q.dtype)
+            elif q.dtype != k.dtype:
+                q = lax.convert_element_type(q, jnp.float32)
+                k = lax.convert_element_type(k, jnp.float32)
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            ks, vs = [lax.convert_element_type(
+                r[hs, pl.ds(lax.mul(j, i32(bk)), bk)], jnp.float32)
+                for r in scales] or [None, None]                 # (1, bk)
+            if ks is not None:
+                s = lax.mul(s, lax.broadcast_in_dim(ks, s.shape, (0, 1)))
+            s = lax.select(allow, lax.mul(s, f32(scale)), masked)
+            _ragged_softmax_step(s, m_refs[h], l_refs[h], acc_refs[h], v, vs)
 
-    @pl.when(p == last_p)
-    def _finish():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = out.astype(o_ref.dtype).reshape(o_ref.shape)
+        @pl.when(tile_done)
+        def _finish():
+            for h in range(K):
+                acc = acc_refs[h][...]
+                out = lax.div(acc, lax.broadcast_in_dim(
+                    lax.max(l_refs[h][...], f32(1e-30)), acc.shape, (0, 1)))
+                o_ref[pl.ds(h, 1), pl.ds(r0, tq), :] = lax.reshape(
+                    lax.convert_element_type(out, o_ref.dtype),
+                    (1,) + out.shape)
+
+        return t2, j2, other
+
+    prefetched = lax.eq(state[0], i32(1))
+
+    @pl.when(lax.bitwise_and(lax.gt(n_tiles, i32(0)),
+                             lax.bitwise_not(prefetched)))
+    def _fetch_first():
+        block_copies(layer, b, i32(0), i32(0), True)
+
+    slot0 = lax.select(prefetched, state[1], i32(0))
+    state[0] = i32(0)
+    lax.while_loop(lambda c: lax.lt(c[0], n_tiles), pair,
+                   (i32(0), i32(0), slot0))
 
 
 def _paged_ragged_call(q, planes, block_table, lengths, q_lens, *, scale,
@@ -143,55 +337,58 @@ def _paged_ragged_call(q, planes, block_table, lengths, q_lens, *, scale,
     G = H // K
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    # (*lead, B, K, Qmax*G, D): one contiguous query block per row
+    page_bytes = sum(math.prod(p.shape[n + 1:]) * p.dtype.itemsize
+                     for p in planes[:2])
+    tq, ppb = block_sizes(Qm * G, T, MP, page_bytes)
+    rows = -(-Qm * G // tq) * tq
+    # (*lead, B, K, Qmax*G, D): one contiguous query block per row, padded
+    # to a whole number of tiles
     qg = jnp.swapaxes(q.reshape(*lead, B, Qm, K, G, D), -4, -3).reshape(
         *lead, B, K, Qm * G, D)
-    # clamp the table so dead pages have a valid physical index (skipped)
+    if rows > Qm * G:
+        qg = jnp.pad(qg, [(0, 0)] * (n + 2) + [(0, rows - Qm * G), (0, 0)])
+    # clamp the table so every entry is a valid physical page
     table = jnp.clip(block_table, 0, P - 1).astype(jnp.int32)
-    unit = (1,) * (n + 1)
+    # each row's int8 scales, gathered by the table (padded to whole
+    # blocks): (*lead, B, K, tokens), tokens along lanes
+    span = -(-MP // ppb) * ppb
+    wide = jnp.pad(table, [(0, 0), (0, span - MP)])
+    row_scales = [jnp.moveaxis(p[..., wide, :, :], -1, -3).reshape(
+        *lead, B, K, span * T) for p in planes[2:]]
+    grid = (lead[0], B) if n else (B,)
 
-    if n:
-        grid = (lead[0], B, MP)
+    def row_spec(shape):
+        return pl.BlockSpec((pl.squeezed,) * (n + 1) + shape,
+                            lambda *ids: ids[:n + 1] + (0,) * len(shape))
 
-        def row_map(l, b, p, tbl, ln, ql):
-            return (l, b, 0, 0, 0)
-
-        def page_map(rank):
-            return lambda l, b, p, tbl, ln, ql: (
-                (l, tbl[b, p]) + (0,) * (rank - 2))
-    else:
-        grid = (B, MP)
-
-        def row_map(b, p, tbl, ln, ql):
-            return (b, 0, 0, 0)
-
-        def page_map(rank):
-            return lambda b, p, tbl, ln, ql: (
-                (tbl[b, p],) + (0,) * (rank - 1))
-
-    row_spec = pl.BlockSpec(unit + (K, Qm * G, D), row_map)
-    page_specs = [pl.BlockSpec(unit + plane.shape[n + 1:],
-                               page_map(plane.ndim)) for plane in planes]
-    kernel = functools.partial(_pa_ragged_kernel, scale=scale,
-                               page_tokens=T, group=G, batch_axis=n)
+    q_spec = row_spec((K, rows, D))
+    kernel = functools.partial(_pa_ragged_kernel, scale=scale, group=G,
+                               tq=tq, batch_axis=n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=grid,
-        in_specs=[row_spec] + page_specs,
-        out_specs=row_spec,
-        scratch_shapes=[
-            pltpu.VMEM((K, Qm * G, 1), jnp.float32),
-            pltpu.VMEM((K, Qm * G, 1), jnp.float32),
-            pltpu.VMEM((K, Qm * G, D), jnp.float32),
+        in_specs=[q_spec]
+        + [pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)] * 2
+        + [row_spec((K, span * T))] * len(row_scales),
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((2, ppb) + p.shape[n + 1:], p.dtype)
+                        for p in planes[:2]] + [
+            pltpu.SemaphoreType.DMA((2,)),
+            *[pltpu.VMEM((tq, 1), jnp.float32)] * (2 * K),
+            *[pltpu.VMEM((tq, D), jnp.float32)] * K,
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid)),
         interpret=interpret,
     )(table, lengths.astype(jnp.int32), q_lens.astype(jnp.int32), qg,
-      *planes)
+      *planes[:2], *row_scales)
+    out = out[..., :Qm * G, :]
     return jnp.swapaxes(out.reshape(*lead, B, K, Qm, G, D), -4, -3).reshape(
         q.shape)
 

@@ -68,6 +68,12 @@ def paged_attention_ragged(q, pool_k, pool_v, block_table, lengths, q_lens,
     lengths: (B,) valid pool tokens including the chunk; q_lens: (B,) valid
     queries per row. Padding query slots and ``q_lens == 0`` rows return
     exactly zero; ``q_lens == 1`` reduces to ``paged_attention``.
+
+    On the TPU the kernel runs one grid step per row and visits only the
+    row's live (query tile × KV block) pairs: tiles of up to 128 of the
+    row's ``Qmax * G`` query rows, blocks of up to 256 tokens of the row's
+    pages, copied from the pool in HBM while the previous block computes
+    (``kernel.py``; ``kernel.ragged_grid_blocks`` counts the pairs).
     """
     if jax.default_backend() == "tpu":
         return paged_attention_ragged_pallas(q, pool_k, pool_v, block_table,

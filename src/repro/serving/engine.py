@@ -61,6 +61,7 @@ transparently.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Optional
@@ -72,6 +73,7 @@ import numpy as np
 from repro.core.clock import SimClock
 from repro.core.engines import EngineSpec, create_kv_engine
 from repro.core.kvcache import KVSpec
+from repro.kernels.paged_attention.kernel import ragged_grid_blocks
 from repro.serving import batching
 from repro.serving.trace import TRACER
 
@@ -274,6 +276,16 @@ class ServingEngine:
             self._step_paged_ragged = jax.jit(model.step_paged_ragged)
             self._scatter_prefill = jax.jit(batching.scatter_prefill_planes,
                                             static_argnums=3)
+        # the (query tile × KV block) pairs the GQA ragged kernel's launch
+        # holds and visits per layer, by the kernel's own block sizes
+        self._attn_blocks = None
+        if self.pooled and self.desc.kernel in ("dense", "int8"):
+            self._attn_blocks = functools.partial(
+                ragged_grid_blocks, group=mcfg.num_heads // kv_heads,
+                page_tokens=cfg.page_tokens, max_pages=self.max_pages,
+                page_bytes=cfg.page_tokens * sum(
+                    p.entry_bytes for p in self.desc.paged_planes
+                    if p.kind == "kv"))
         # ----------------------------------------- speculative decode (I7)
         # draft-and-verify over the ragged entries: decode rows carry
         # 1 + k query slots, the per-slot logits of the SAME fused forward
@@ -601,6 +613,11 @@ class ServingEngine:
                     tbl_p[:B] = tbl
                     ctx_p = np.zeros(Bb, np.int32)
                     ctx_p[:B] = ctx
+                    if fused and self._attn_blocks is not None:
+                        every, live = self._attn_blocks(qarr, ctx_p + qarr,
+                                                        qmax=Qb)
+                        TRACER.count("attn.blocks.all", every)
+                        TRACER.count("attn.blocks.live", live)
                     cache = {"block_table": jnp.asarray(tbl_p)}
                     for n, v in zip(names, self.tiered.pool_views()):
                         cache["pool_" + n] = v

@@ -55,9 +55,9 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _ragged_args(one_chip, lead, qmax, int8):
+def _ragged_args(one_chip, lead, qmax, int8, batch=BATCH):
     """(q, planes..., block_table, lengths, q_lens) at InternLM2-1.8B widths
-    over a 1 GiB pool's page count."""
+    over a 1 GiB pool's page count, for ``batch`` rows."""
     cfg = get_config("internlm2-1.8b")
     H, K, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     pages = POOL_BYTES // (cfg.num_layers * PAGE_TOKENS * K * D * 2 * 2)
@@ -67,10 +67,10 @@ def _ragged_args(one_chip, lead, qmax, int8):
         planes += [_spec(one_chip, lead + (pages, PAGE_TOKENS, K),
                          jnp.bfloat16)] * 2
     i32 = jnp.int32
-    return ([_spec(one_chip, lead + (BATCH, qmax, H, D), jnp.bfloat16)]
-            + planes + [_spec(one_chip, (BATCH, MAX_PAGES), i32),
-                        _spec(one_chip, (BATCH,), i32),
-                        _spec(one_chip, (BATCH,), i32)])
+    return ([_spec(one_chip, lead + (batch, qmax, H, D), jnp.bfloat16)]
+            + planes + [_spec(one_chip, (batch, MAX_PAGES), i32),
+                        _spec(one_chip, (batch,), i32),
+                        _spec(one_chip, (batch,), i32)])
 
 
 @pytest.mark.parametrize("entry,lead,int8", [
@@ -80,9 +80,13 @@ def _ragged_args(one_chip, lead, qmax, int8):
     (paged_attention_layers_ragged_q8_pallas, (4,), True),
 ], ids=["dense", "int8", "dense-layers", "int8-layers"])
 @pytest.mark.parametrize("qmax", [1, QMAX])
+@pytest.mark.parametrize("batch", [2, 4, BATCH])
 def test_gqa_ragged_kernel_compiles_for_v5e(one_chip, entry, lead, int8,
-                                            qmax):
-    compiled = _compile(entry, *_ragged_args(one_chip, lead, qmax, int8))
+                                            qmax, batch):
+    """The served batch-width buckets and the decode and chunk Qmax: the
+    query tiles, the KV blocks' page copies and their VMEM fit."""
+    compiled = _compile(entry, *_ragged_args(one_chip, lead, qmax, int8,
+                                             batch))
     assert "tpu_custom_call" in compiled.as_text()
 
 

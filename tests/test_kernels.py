@@ -23,6 +23,8 @@ from repro.kernels import (flash_attention, log_patch, mla_paged_attention,
                            paged_attention_ragged_q8)
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.log_patch.ref import log_patch_ref
+from repro.kernels.paged_attention.kernel import (block_sizes,
+                                                  ragged_grid_blocks)
 from repro.kernels.paged_attention.ref import (
     mla_paged_attention_layers_ragged_ref,
     paged_attention_layers_ragged_q8_ref, paged_attention_layers_ragged_ref,
@@ -148,16 +150,31 @@ def test_paged_attention_contract_edges(entry):
 
 # ----------------------------------------------------- ragged-query entries
 RAGGED_CASES = [
-    # (L, B, Qmax, H, K, D, page_tokens, pool_pages, max_pages)
+    # (L, B, Qmax, H, K, D, page_tokens, pool_pages, max_pages), lengths
+    # and q_lens drawn from the seed
     (2, 3, 4, 8, 4, 64, 16, 24, 6),
     (1, 1, 8, 4, 4, 128, 8, 8, 4),      # one long chunk row
     (3, 2, 2, 16, 2, 64, 32, 10, 4),    # large GQA group
     (2, 4, 1, 8, 8, 256, 16, 40, 4),    # Qmax=1 degenerate (pure decode)
 ]
+# The kernel's tiles and blocks at work: the same tuple, then the lengths
+# and q_lens. At 16-token pages a KV block is 16 pages (256 tokens), and a
+# query tile is 128 rows of Qmax * G.
+TILED_CASES = [
+    # a decode row beside a 256-token chunk row (4 tiles, 2 blocks) in one
+    # launch, and a q_len = 0 row with a nonzero length
+    (2, 3, 256, 4, 2, 64, 16, 60, 20, [300, 40 + 256, 7], [1, 256, 0]),
+    # lengths ending mid-page past a block, on the block boundary, one page
+    # past it, and a row with fewer live pages than one block
+    (2, 4, 4, 8, 4, 64, 16, 96, 24, [256 + 7, 256, 256 + 16, 40],
+     [3, 4, 1, 2]),
+    # Qmax * G = 144 rows: a whole tile and a part of one
+    (1, 2, 72, 4, 2, 64, 16, 40, 20, [72 + 100, 30], [72, 5]),
+]
 
 
 def _ragged_inputs(case, dtype, seed=12):
-    L, B, Qm, H, K, D, T, P, MP = case
+    L, B, Qm, H, K, D, T, P, MP = case[:9]
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.standard_normal((L, B, Qm, H, D)), dtype)
     pk = jnp.asarray(rng.standard_normal((L, P, T, K, D)), dtype)
@@ -165,10 +182,19 @@ def _ragged_inputs(case, dtype, seed=12):
     tbl = jnp.asarray(rng.integers(0, P, (B, MP)), jnp.int32)
     qls = rng.integers(1, Qm + 1, B).astype(np.int32)
     lens = (rng.integers(0, T * MP - Qm, B) + qls).astype(np.int32)
+    if len(case) > 9:
+        lens, qls = (np.asarray(x, np.int32) for x in case[9:])
     return q, pk, pv, tbl, jnp.asarray(lens), jnp.asarray(qls)
 
 
-@pytest.mark.parametrize("case", RAGGED_CASES)
+def _assert_padding_zero(out, qls):
+    """Exact zeros in every query slot at or past a row's q_len (the
+    whole row where q_len is 0); ``out`` is (L, B, Qmax, H, D)."""
+    for b, n in enumerate(np.asarray(qls)):
+        assert np.all(out[:, b, int(n):] == 0.0), b
+
+
+@pytest.mark.parametrize("case", RAGGED_CASES + TILED_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_ragged_matches_oracle(case, dtype):
     q, pk, pv, tbl, lens, qls = _ragged_inputs(case, dtype)
@@ -178,9 +204,10 @@ def test_paged_attention_ragged_matches_oracle(case, dtype):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=5 * _tol(dtype), rtol=2 * _tol(dtype))
+    _assert_padding_zero(np.asarray(out)[None], qls)
 
 
-@pytest.mark.parametrize("case", RAGGED_CASES)
+@pytest.mark.parametrize("case", RAGGED_CASES + TILED_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention_layers_ragged_matches_oracle(case, dtype):
     q, pk, pv, tbl, lens, qls = _ragged_inputs(case, dtype)
@@ -190,6 +217,44 @@ def test_paged_attention_layers_ragged_matches_oracle(case, dtype):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=5 * _tol(dtype), rtol=2 * _tol(dtype))
+    _assert_padding_zero(np.asarray(out), qls)
+
+
+@pytest.mark.parametrize("q_rows,page_tokens,max_pages,page_bytes", [
+    (2, 16, 160, 65536),          # InternLM2-1.8B decode program, bf16
+    (512, 16, 160, 65536),        # its (8, 256) chunk program
+    (512, 16, 160, 32768),        # the same over int8 pages
+    (144, 8, 40, 4096),           # a tile and a part of one
+    (6, 32, 4, 8192),             # a table narrower than one block
+    (8, 16, 64, 1 << 20),         # pages the VMEM budget caps
+])
+def test_ragged_grid_blocks_is_a_brute_force_count(q_rows, page_tokens,
+                                                   max_pages, page_bytes):
+    """The host count of (query tile × KV block) pairs equals a brute-force
+    walk over every pair of every row: a pair is visited when one of the
+    tile's live query rows attends a position inside the block."""
+    group = 2
+    qmax = q_rows // group
+    tq, ppb = block_sizes(q_rows, page_tokens, max_pages, page_bytes)
+    bk, cap = ppb * page_tokens, max_pages * page_tokens
+    rng = np.random.default_rng(40)
+    q_lens = np.concatenate([[0, 1, qmax], rng.integers(0, qmax + 1, 9)])
+    lengths = np.minimum(q_lens + rng.integers(0, cap, 12), cap)
+    lengths[0] = 5                     # q_len 0 with pooled tokens
+    n_tiles, n_blocks = -(-q_rows // tq), -(-max_pages // ppb)
+    live = 0
+    for q, ln in zip(q_lens, lengths):
+        for t in range(n_tiles):
+            rows = [r for r in range(t * tq, (t + 1) * tq)
+                    if r < q * group]
+            for j in range(n_blocks):
+                live += any(j * bk <= ln - q + r // group
+                            for r in rows)
+    got = ragged_grid_blocks(q_lens, lengths, qmax=qmax, group=group,
+                             page_tokens=page_tokens, max_pages=max_pages,
+                             page_bytes=page_bytes)
+    assert got == (len(q_lens) * n_tiles * n_blocks, live)
+    assert 0 < live < got[0]
 
 
 def test_ragged_qlen1_is_bitwise_decode_kernel():
@@ -476,10 +541,27 @@ def _mla_inputs(seed=32):
     return q_c, q_r, pc, pkr, tbl, lens, qls, scale
 
 
-def test_q8_ragged_matches_oracle_and_pads_zero():
+def _q8_tiled_inputs(case, seed=38):
+    """int8 pools and scale planes at a ``TILED_CASES`` case."""
+    L, B, Qm, H, K, D, T, P, MP, lens, qls = case
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((L, B, Qm, H, D)), jnp.float32)
+    pk = jnp.asarray(rng.integers(-127, 128, (L, P, T, K, D)), jnp.int8)
+    pv = jnp.asarray(rng.integers(-127, 128, (L, P, T, K, D)), jnp.int8)
+    ks = jnp.asarray(rng.random((L, P, T, K)) * 0.1 + 0.01, jnp.bfloat16)
+    vs = jnp.asarray(rng.random((L, P, T, K)) * 0.1 + 0.01, jnp.bfloat16)
+    tbl = jnp.asarray(rng.integers(0, P, (B, MP)), jnp.int32)
+    return (q, pk, pv, ks, vs, tbl, jnp.asarray(lens, jnp.int32),
+            jnp.asarray(qls, jnp.int32))
+
+
+@pytest.mark.parametrize("case", [None] + TILED_CASES)
+def test_q8_ragged_matches_oracle_and_pads_zero(case):
     """int8 ragged entries vs the pure-jnp oracle, plus exact zeros in every
-    padding query slot (q_len = 0 rows included)."""
-    q, pk, pv, ks, vs, tbl, lens, qls = _q8_inputs()
+    padding query slot (q_len = 0 rows included): the small batch, and the
+    kernel's tiles and blocks at work."""
+    q, pk, pv, ks, vs, tbl, lens, qls = (
+        _q8_inputs() if case is None else _q8_tiled_inputs(case))
     out = paged_attention_layers_ragged_q8(q, pk, pv, ks, vs, tbl, lens,
                                            qls, force_pallas=True)
     ref = paged_attention_layers_ragged_q8_ref(q, pk, pv, ks, vs, tbl,

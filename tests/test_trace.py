@@ -7,8 +7,8 @@ served tick records with it.
   at one constant offset from the ring's starts;
 * the served tick at a reduced size: spans nest as the scheduler and
   engine open them, ``serve.tick.n`` counts the ticks run, and
-  ``host_syncs``, ``rows.*`` and ``slots.*`` equal what the run's steps
-  and its one preemption give when counted by hand;
+  ``host_syncs``, ``rows.*``, ``slots.*`` and ``attn.blocks.*`` equal what
+  the run's steps and its one preemption give when counted by hand;
 * the fused step's ``jax.named_scope``s reach the compiled ops' metadata.
 """
 import glob
@@ -22,6 +22,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.engines import EngineSpec
+from repro.kernels.paged_attention.kernel import ragged_grid_blocks
 from repro.models import build_model
 from repro.serving import Request, Scheduler, ServeConfig, ServingEngine
 from repro.serving.trace import TRACER, Tracer
@@ -159,11 +160,12 @@ def _served_run(lm):
     reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, n,
                                                dtype=np.int32), max_new=5)
             for i, n in enumerate((8, 12, 8))]
-    steps = []
+    steps, ctxs = [], []
     step = eng.step_batch
 
     def recording(rids, caches, tok_rows, *a, **kw):
         steps.append([len(t) for t in tok_rows])
+        ctxs.append([int(np.asarray(c["pos"])[0]) for c in caches])
         return step(rids, caches, tok_rows, *a, **kw)
 
     eng.step_batch = recording
@@ -184,7 +186,7 @@ def _served_run(lm):
     after = TRACER.counters()
     delta = {k: v - before.get(k, 0) for k, v in after.items()}
     assert all(r.done for r in reqs)
-    return eng, sched, reqs, steps, ticks, victim_pages, delta
+    return eng, sched, reqs, steps, ctxs, ticks, victim_pages, delta
 
 
 PARENT = {"serve.admit": "serve.tick", "serve.restore": "serve.tick",
@@ -196,7 +198,7 @@ PARENT = {"serve.admit": "serve.tick", "serve.restore": "serve.tick",
 
 
 def test_served_tick_spans_nest_as_the_layers_open_them(lm):
-    _, sched, reqs, _, ticks, _, delta = _served_run(lm)
+    _, sched, reqs, _, _, ticks, _, delta = _served_run(lm)
     spans = TRACER.spans()
     by_id = {s.id: s for s in spans}
     names = {s.name for s in spans}
@@ -219,7 +221,7 @@ def test_served_tick_spans_nest_as_the_layers_open_them(lm):
 
 
 def test_served_tick_counters_match_a_hand_count(lm):
-    eng, _, _, steps, _, victim_pages, delta = _served_run(lm)
+    eng, _, _, steps, ctxs, _, victim_pages, delta = _served_run(lm)
     planes = len(eng.desc.paged_planes)
     decode = sum(q == 1 for qs in steps for q in qs)
     chunk = sum(q > 1 for qs in steps for q in qs)
@@ -236,6 +238,22 @@ def test_served_tick_counters_match_a_hand_count(lm):
     assert delta["slots.all"] == all_slots
     assert delta["slots.pad"] == all_slots - sum(map(sum, steps))
     assert delta["serve.step.n"] == delta["serve.launch.n"] == len(steps)
+    # the attention kernel's (query tile × KV block) pairs, held and
+    # visited, by the kernel's own count over each step's padded rows
+    cfg = lm[0]
+    page_bytes = PAGE * sum(p.entry_bytes for p in eng.desc.paged_planes)
+    every = live = 0
+    for qs, cs in zip(steps, ctxs):
+        pad = _pow2(len(qs)) - len(qs)
+        q = np.asarray(qs + [0] * pad)
+        a, v = ragged_grid_blocks(
+            q, np.asarray(cs + [0] * pad) + q, qmax=_pow2(max(qs)),
+            group=cfg.num_heads // cfg.num_kv_heads, page_tokens=PAGE,
+            max_pages=eng.max_pages, page_bytes=page_bytes)
+        every, live = every + a, live + v
+    assert delta["attn.blocks.all"] == every
+    assert delta["attn.blocks.live"] == live
+    assert 0 < live < every
 
 
 def test_fused_step_ops_carry_the_named_scopes(lm):
