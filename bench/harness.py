@@ -87,29 +87,25 @@ class CompileCounter:
 def build(cfg: dict, seed: int, program_cfg=None):
     """The program's model at the configuration's published widths, with
     the benchmark's weights from ``seed``. ``program_cfg`` overrides the
-    program's registry entry (tests run a reduced one)."""
+    program's registry entry (tests run a reduced one). The registry entry
+    has to hold every field the configuration's family states: a float to
+    a relative 1e-9, anything else exactly."""
     from repro.configs import get_config
     from repro.models import build_model
 
+    import families
     import weights
 
     pc = program_cfg or get_config(cfg["program_arch"])
-    widths = {"d_model": "hidden_size", "d_ff": "intermediate_size",
-              "num_heads": "num_attention_heads",
-              "num_kv_heads": "num_key_value_heads",
-              "num_layers": "num_hidden_layers", "vocab_size": "vocab_size",
-              "tie_embeddings": "tie_word_embeddings"}
-    differ = {k: (getattr(pc, k), cfg[v]) for k, v in widths.items()
-              if getattr(pc, k) != cfg[v]}
-    n = cfg["num_hidden_layers"]
-    scales = {"rope_theta": cfg["rope_theta"], "norm_eps": cfg["rms_norm_eps"],
-              "embedding_scale": cfg.get("scale_emb", 1.0),
-              "residual_scale": cfg["scale_depth"] / n ** 0.5
-              if "scale_depth" in cfg else 1.0,
-              "logit_scale": cfg["dim_model_base"] / cfg["hidden_size"]
-              if "dim_model_base" in cfg else 1.0}
-    differ.update({k: (getattr(pc, k), v) for k, v in scales.items()
-                   if abs(getattr(pc, k) - v) > 1e-9 * abs(v)})
+    differ = {}
+    for name, want in families.load(cfg).program_fields(cfg).items():
+        have = program_field(pc, name)
+        if isinstance(want, float) and isinstance(have, (int, float)):
+            same = abs(have - want) <= 1e-9 * abs(want)
+        else:
+            same = have == want
+        if not same:
+            differ[name] = (have, want)
     if differ:
         raise RuntimeError(f"the program's {pc.name} differs from "
                            f"{cfg['name']}: {differ}")
@@ -118,6 +114,14 @@ def build(cfg: dict, seed: int, program_cfg=None):
     weights.check_against(jax.eval_shape(model.init, jax.random.key(0)),
                           cfg)
     return model, weights.make_params(cfg, seed)
+
+
+def program_field(pc, name: str):
+    """Attribute ``name`` of the program's config, dotted through its
+    sub-configs (``moe.num_experts``); None where a step is missing."""
+    for part in name.split("."):
+        pc = getattr(pc, part, None)
+    return pc
 
 
 def make_engine(model, params, cfg: dict):

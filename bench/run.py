@@ -6,9 +6,10 @@ The cell is an entry of ``BENCHMARK.json``'s ``workloads``; its
 configuration, traffic mix, rate and check limit are found by name under
 ``bench/``. The run makes its requests and weights from ``--seed``, warms
 every program those requests reach (set-up), serves for ``--seconds``,
-then checks the served tokens against the plain float32 reference
-(``bench/reference.py``) on a sample drawn from the seed. It exits non-zero,
-printing no result, when JAX finds no TPU or fewer chips than the cell asks.
+then checks the served tokens against the plain float32 reference (its
+family's ``logits``, ``bench/families/<model_type>.py``) on a sample drawn
+from the seed. It exits non-zero, printing no result, when JAX finds no
+TPU or fewer chips than the cell asks.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
@@ -39,6 +40,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+import families  # noqa: E402
 import harness  # noqa: E402
 import reference  # noqa: E402
 import trace_reduce  # noqa: E402
@@ -49,7 +51,8 @@ CACHE_DIR = ROOT / ".jax_cache"
 
 
 class Cell:
-    """A workload entry of ``BENCHMARK.json`` with its files resolved."""
+    """A workload entry of ``BENCHMARK.json`` with its files resolved, its
+    configuration's family module among them."""
 
     def __init__(self, spec: dict, name: str, root: Path = ROOT):
         cells = {w["name"]: w for w in spec["workloads"]}
@@ -62,6 +65,7 @@ class Cell:
         self.cfg = traffic.load_json(root / configs[self.entry["config"]]
                                      ["file"])
         bench = root / "bench"
+        self.family = families.load(self.cfg, bench / "families")
         self.mix = traffic.load_json(bench / "traffic" /
                                      f"{self.entry['traffic']}.json")
         self.params = traffic.load_json(bench / "cells" / f"{name}.json")

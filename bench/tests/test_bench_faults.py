@@ -20,10 +20,11 @@ LIMIT = 0.01
 
 
 def _cell(name):
+    """The cell at its family's CPU size, with a pool, rows and a mix to
+    match."""
     cell = run.Cell(SPEC, name)
-    cell.cfg = dict(cell.cfg, hidden_size=128, intermediate_size=256,
-                    num_attention_heads=4, num_key_value_heads=2,
-                    num_hidden_layers=2, vocab_size=512, max_len=128,
+    widths, _ = cell.family.small(cell.cfg)
+    cell.cfg = dict(cell.cfg, **widths, max_len=128,
                     prefill_chunk_tokens=32, kv_pool_bytes=1 << 20,
                     max_batch_seqs=4)
     cell.mix = copy.deepcopy(cell.mix)
@@ -37,16 +38,13 @@ def _cell(name):
 
 
 def _run(name, **kw):
-    pc = get_config(SPEC_ARCH[name]).reduced(num_kv_heads=2)
-    return run.run_cell(_cell(name), 2**31 + 5, 3.0, False, program_cfg=pc,
+    cell = _cell(name)
+    _, reduced = cell.family.small(cell.cfg)
+    pc = get_config(cell.cfg["program_arch"]).reduced(**reduced)
+    return run.run_cell(cell, 2**31 + 5, 3.0, False, program_cfg=pc,
                         require_tpu=False, **kw)
 
 
-SPEC_ARCH = {w["name"]: next(c for c in SPEC["configs"]
-                             if c["name"] == w["config"])["file"]
-             for w in SPEC["workloads"]}
-SPEC_ARCH = {k: json.loads((run.ROOT / v).read_text())["program_arch"]
-             for k, v in SPEC_ARCH.items()}
 CELL = "internlm2-1.8b.conversation"
 
 

@@ -1,5 +1,6 @@
 """BENCHMARK.json keeps to its contract, and every cell's configuration,
-traffic mix, cell file and metric reader resolves by name."""
+family module, traffic mix, cell file and metric reader resolves by
+name."""
 import json
 import re
 
@@ -59,8 +60,10 @@ def test_cell_files_resolve(name):
     cfg = cell.cfg
     for key in ("hidden_size", "num_hidden_layers", "page_tokens",
                 "kv_pool_bytes", "max_batch_seqs", "prefill_chunk_tokens",
-                "max_len", "source", "reduced", "assumed", "program_arch"):
+                "max_len", "source", "reduced", "assumed", "program_arch",
+                "model_type"):
         assert key in cfg
+    assert cell.family.__file__.endswith(f"{cfg['model_type']}.py")
     assert cell.mix["arrival"] in ("poisson", "backlog")
     if cell.mix["arrival"] != "backlog":
         assert cell.params["rate_rps"] > 0

@@ -1,16 +1,19 @@
 """Random weights from the seed, made by the benchmark and not the program.
 
-The program takes its parameters as a pytree; this module states that tree
-for the dense GQA family (names and shapes) from a configuration file, and
-fills it on the device in one jitted call: matrices from
-N(0, initializer_range), norm scales from 1 + N(0, 0.1), every leaf from its
-own key folded from the seed. The reference calls the same function again
-after the program's state is freed, so it takes nothing the program made.
+The program takes its parameters as a pytree; the configuration's family
+(``layout`` in ``bench/families/<model_type>.py``) states that tree (names
+and shapes), and this module fills it on the device in one jitted call:
+matrices from N(0, initializer_range), norm scales from 1 + N(0, 0.1),
+every leaf from its own key folded from the seed. The reference calls the
+same function again after the program's state is freed, so it takes
+nothing the program made.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+import families
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 NORM_STD = 0.1
@@ -22,28 +25,6 @@ def padded_vocab(cfg: dict, multiple: int = 256) -> int:
     masked."""
     v = cfg["vocab_size"]
     return -(-v // multiple) * multiple
-
-
-def layout(cfg: dict) -> dict:
-    """``{path: shape}`` of the parameter tree, path as a tuple of keys."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, k = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    dh = d // h
-    n_layers = cfg["num_hidden_layers"]
-    vp = padded_vocab(cfg)
-    out = {("embed", "table"): (vp, d), ("final_ln", "scale"): (d,)}
-    if not cfg["tie_word_embeddings"]:
-        out[("head", "table")] = (vp, d)
-    per_layer = {
-        ("ln_attn", "scale"): (d,), ("ln_ffn", "scale"): (d,),
-        ("attn", "wq"): (d, h * dh), ("attn", "wk"): (d, k * dh),
-        ("attn", "wv"): (d, k * dh), ("attn", "wo"): (h * dh, d),
-        ("ffn", "w_gate"): (d, f), ("ffn", "w_up"): (d, f),
-        ("ffn", "w_down"): (f, d),
-    }
-    for path, shape in per_layer.items():
-        out[("blocks",) + path] = (n_layers,) + shape
-    return out
 
 
 def seed_key(seed: int):
@@ -65,7 +46,7 @@ def _nest(flat: dict) -> dict:
 def make_params(cfg: dict, seed: int):
     """The whole parameter tree on the default device, in the served dtype,
     from one jitted call."""
-    shapes = layout(cfg)
+    shapes = families.load(cfg).layout(cfg)
     dtype = DTYPES[cfg["param_dtype"]]
     std = float(cfg["initializer_range"])
 
@@ -87,7 +68,8 @@ def check_against(params_shape, cfg: dict) -> None:
     flat = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(params_shape)[0]:
         flat[tuple(p.key for p in path)] = tuple(leaf.shape)
-    want = {p: tuple(s) for p, s in layout(cfg).items()}
+    want = {p: tuple(s)
+            for p, s in families.load(cfg).layout(cfg).items()}
     if flat != want:
         missing = sorted(set(want) - set(flat))
         extra = sorted(set(flat) - set(want))
